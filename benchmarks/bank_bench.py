@@ -143,6 +143,9 @@ def main(argv=None) -> None:
     ap.add_argument("--tiny", action="store_true", help="CI smoke shapes")
     ap.add_argument("--out", default="BENCH_bank.json")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.tiny:
         fused_kw = dict(bank=8, d=4, dfeat=64)

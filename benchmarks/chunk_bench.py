@@ -14,10 +14,12 @@ the JSON artifact records prediction AND observation:
   is cheap so CPU numbers are a baseline; the model column (collectives per
   tick, payload bytes per collective) is what transfers to ICI/DCN.
 
-Run as a script to emit ``BENCH_chunk.json`` (sets XLA_FLAGS before first
-jax use so the sharded sweep actually distributes):
+Run as a script to emit ``BENCH_chunk.json``. It runs on the default JAX
+backend; for a CPU run set ``JAX_PLATFORMS=cpu``, and the script forces
+``--shards`` host devices (XLA_FLAGS, before first jax use) so the sharded
+sweep actually distributes:
 
-    python benchmarks/chunk_bench.py --shards 8 --out BENCH_chunk.json
+    JAX_PLATFORMS=cpu python benchmarks/chunk_bench.py --shards 8 --out BENCH_chunk.json
     python benchmarks/chunk_bench.py --tiny   # CI smoke -> /tmp by default
 
 Without an explicit ``--out``, a ``--tiny`` run writes to /tmp so tiny
@@ -221,7 +223,9 @@ def main(argv=None) -> None:
         "XLA_FLAGS",
         f"--xla_force_host_platform_device_count={args.shards}",
     )
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.tiny:
         disp_kw = dict(bank=4, d=4, dfeat=64, n_ticks=64, iters=2)

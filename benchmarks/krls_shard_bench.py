@@ -2,10 +2,11 @@
 two-pass KRLS bank tick. Emits ``BENCH_krls.json`` (the CI bench-smoke
 artifact recording the perf trajectory per PR).
 
-Run as a script — it forces a multi-device host platform *before* first jax
-use, so the sharded path actually distributes:
+Run as a script. It runs on the default JAX backend; for a CPU run set
+``JAX_PLATFORMS=cpu``, and the script forces a multi-device host platform
+*before* first jax use, so the sharded path actually distributes:
 
-    python benchmarks/krls_shard_bench.py --shards 8 --out BENCH_krls.json
+    JAX_PLATFORMS=cpu python benchmarks/krls_shard_bench.py --shards 8 --out BENCH_krls.json
     python benchmarks/krls_shard_bench.py --tiny   # CI smoke shapes
 
 On CPU the sharded tick is expected to LOSE to dense (host "devices" share
@@ -154,7 +155,9 @@ def main(argv=None) -> None:
         "XLA_FLAGS",
         f"--xla_force_host_platform_device_count={args.shards}",
     )
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.tiny:
         dfeats, bank, dfeat_bank, iters = [64, 128], 4, 64, 3

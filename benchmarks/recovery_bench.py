@@ -219,6 +219,9 @@ def main(argv=None) -> int:
     parser.add_argument("--tiny", action="store_true",
                         help="CI smoke shapes (never the committed baseline)")
     args = parser.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

@@ -135,6 +135,9 @@ def main(argv=None) -> None:
     ap.add_argument("--tiny", action="store_true", help="CI smoke shapes")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.out is None:
         # Tiny runs must not clobber the committed full-shape baseline.
         args.out = (

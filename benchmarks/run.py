@@ -102,6 +102,9 @@ def main() -> None:
         "--no-json", action="store_true", help="skip writing BENCH_*.json",
     )
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     scale = 4 if args.full else 1
     benches = {

@@ -206,6 +206,9 @@ def main(argv=None) -> int:
                         help="checkpoint/restore round-trip on the first "
                              "recorded config (asserts a bitwise match)")
     args = parser.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

@@ -18,11 +18,12 @@ import jax.numpy as jnp
 from repro.core.distributed import diffusion_klms_run
 from repro.core.rff import sample_rff
 from repro.data.synthetic import gen_nonlinear_wiener
+from repro.launch.mesh import make_mesh
 
 
 def main():
     nodes = 8
-    mesh = jax.make_mesh((nodes,), ("data",))
+    mesh = make_mesh((nodes,), ("data",))
     rff = sample_rff(jax.random.PRNGKey(0), 5, 100, sigma=5.0)
 
     # one common unknown system, observed as per-node streams

@@ -30,31 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.klms import LMSState, rff_klms_init, rff_klms_step
 from repro.core.rff import RFF
 
-# ``shard_map`` moved from jax.experimental to the jax namespace (and the
-# experimental module was later removed); support both spellings.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _mark_varying(tree, axis: str):
-    """Mark a pytree as device-varying over ``axis`` (newer-jax carry typing).
-
-    The marking primitive is ``jax.lax.pcast`` on current jax and
-    ``jax.lax.pvary`` on the releases that introduced varying types; on
-    older jax neither exists, every value is implicitly varying, and this
-    is the identity.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return jax.tree.map(lambda a: pcast(a, axis, to="varying"), tree)
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return jax.tree.map(lambda a: pvary(a, axis), tree)
-    return tree
-
-
 __all__ = [
     "DiffusionState",
     "diffusion_klms_run",
@@ -99,7 +74,9 @@ def _node_stream(
     )
     # the carry becomes device-varying after one data-dependent update;
     # mark the init as varying so scan's carry types match.
-    state = _mark_varying(state, axis)
+    state = jax.tree.map(
+        lambda a: jax.lax.pcast(a, axis, to="varying"), state
+    )
 
     def combine(theta: jax.Array, comp_err: jax.Array):
         if not compress:
@@ -153,7 +130,7 @@ def diffusion_klms_run(
         axis=axis,
     )
     spec = P(axis)
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         lambda x, y: body(xs=x, ys=y),
         mesh=mesh,
         in_specs=(spec, spec),

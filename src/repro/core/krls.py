@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.distributed import _mark_varying, _shard_map
 from repro.core.klms import StepOut
 from repro.features.base import (
     FeatureLike,
@@ -43,6 +42,7 @@ from repro.features.base import (
     featurize,
 )
 from repro.kernels.chunking import time_blocks, unblock_time, valid_time_mask
+from repro.kernels.ref import F32_DOT
 
 __all__ = [
     "RLSState",
@@ -59,6 +59,11 @@ __all__ = [
     "make_sharded_krls_predict",
     "sharded_krls_run",
 ]
+
+
+# Every f32 product here asks for full f32 precision: a TPU otherwise runs
+# it as one bf16 pass, far outside the state's f32 contract.
+_mm = functools.partial(jnp.matmul, precision=F32_DOT)
 
 
 class RLSState(NamedTuple):
@@ -85,10 +90,10 @@ def rls_step(
     beta: float,
 ) -> tuple[jax.Array, jax.Array, StepOut]:
     """One EW-RLS update in feature space; returns (theta, P, out)."""
-    y_hat = theta @ z
+    y_hat = _mm(theta, z)
     err = y - y_hat
-    pz = pmat @ z
-    denom = beta + z @ pz
+    pz = _mm(pmat, z)
+    denom = beta + _mm(z, pz)
     gain = pz / denom
     theta = theta + gain * err
     pmat = (pmat - jnp.outer(gain, pz)) / beta
@@ -265,10 +270,10 @@ def _sharded_rls_tick(
     dloc = theta_l.shape[0]
     offset = jax.lax.axis_index(axis) * dloc
 
-    z_l = scale_l * jnp.cos(x @ omega_l + bias_l)  # (Dn,) local slice
+    z_l = scale_l * jnp.cos(_mm(x, omega_l) + bias_l)  # (Dn,) local slice
 
-    pz_part = z_l @ pmat_l  # (D,) — P^T z contribution of our rows (P sym)
-    yhat_part = z_l @ theta_l  # () partial prediction
+    pz_part = _mm(z_l, pmat_l)  # (D,) — P^T z contribution of our rows (P sym)
+    yhat_part = _mm(z_l, theta_l)  # () partial prediction
     z_scat = jax.lax.dynamic_update_slice(
         jnp.zeros((dfull,), z_l.dtype), z_l, (offset,)
     )
@@ -279,7 +284,7 @@ def _sharded_rls_tick(
     z = packed[dfull : 2 * dfull]
     y_hat = packed[2 * dfull]
     err = y - y_hat
-    inv_denom = 1.0 / (beta + z @ pz)
+    inv_denom = 1.0 / (beta + _mm(z, pz))
 
     pz_l = jax.lax.dynamic_slice(pz, (offset,), (dloc,))
     theta_l = theta_l + (err * inv_denom) * pz_l
@@ -324,9 +329,9 @@ def _sharded_rls_block_tick(
     dloc = theta_l.shape[0]
     offset = jax.lax.axis_index(axis) * dloc
 
-    z_l = scale_l * jnp.cos(xs @ omega_l + bias_l)  # (k, Dn) local slices
-    pz0_part = z_l @ pmat_l  # (k, D) — P_0^T z_j contributions (P sym)
-    yhat0_part = z_l @ theta_l  # (k,) partial block-start predictions
+    z_l = scale_l * jnp.cos(_mm(xs, omega_l) + bias_l)  # (k, Dn) local slices
+    pz0_part = _mm(z_l, pmat_l)  # (k, D) — P_0^T z_j contributions (P sym)
+    yhat0_part = _mm(z_l, theta_l)  # (k,) partial block-start predictions
     zero = jnp.zeros((), offset.dtype)  # match axis_index dtype under x64
     z_scat = jax.lax.dynamic_update_slice(
         jnp.zeros((k, dfull), z_l.dtype), z_l, (zero, offset)
@@ -346,13 +351,13 @@ def _sharded_rls_block_tick(
         v = pz0[j]
         yh = yhat0[j]
         for i in range(j):
-            c = pzs[i] @ z[j]
+            c = _mm(pzs[i], z[j])
             corr = c * inv_dens[i]
             v = (v - (mask[i] * corr) * pzs[i]) / jnp.where(
                 mask[i] > 0, beta, 1.0
             )
             yh = yh + errs_m[i] * corr
-        inv_den = 1.0 / (beta + z[j] @ v)
+        inv_den = 1.0 / (beta + _mm(z[j], v))
         e = ys[j] - yh
         pzs.append(v)
         inv_dens.append(inv_den)
@@ -366,7 +371,7 @@ def _sharded_rls_block_tick(
     pz_mat = jnp.stack(pzs)  # (k, D)
     pz_loc = jax.lax.dynamic_slice(pz_mat, (zero, offset), (k, dloc))
     coeff = jnp.stack(errs_m) * jnp.stack(inv_dens)  # (k,)
-    theta_l = theta_l + coeff @ pz_loc
+    theta_l = theta_l + _mm(coeff, pz_loc)
     for j in range(k):
         downd = (
             pmat_l - jnp.outer(pz_loc[j], pz_mat[j]) * inv_dens[j]
@@ -401,7 +406,7 @@ def make_sharded_krls_step(
         )
         return theta_l, pmat_l, step + 1, out
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -452,7 +457,7 @@ def make_sharded_krls_block_step(
         )
         return theta_l, pmat_l, step + k, out
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -482,10 +487,10 @@ def make_sharded_krls_predict(
     fspec = krls_feature_specs(axis)
 
     def body(omega_l, bias_l, scale_l, theta_l, x):
-        z_l = scale_l * jnp.cos(x @ omega_l + bias_l)
-        return jax.lax.psum(z_l @ theta_l, axis)
+        z_l = scale_l * jnp.cos(_mm(x, omega_l) + bias_l)
+        return jax.lax.psum(_mm(z_l, theta_l), axis)
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(fspec.omega, fspec.bias, fspec.scale, P(axis), P()),
@@ -518,8 +523,6 @@ def _sharded_krls_run_program(
     if k == 1:
 
         def node(omega_l, bias_l, scale_l, theta_l, pmat_l, step, xs, ys):
-            carry0 = _mark_varying((theta_l, pmat_l), axis)
-
             def body(carry, xy):
                 th, pm = carry
                 x, y = xy
@@ -528,7 +531,11 @@ def _sharded_krls_run_program(
                 )
                 return (th, pm), out
 
-            (theta_l, pmat_l), outs = jax.lax.scan(body, carry0, (xs, ys))
+            # The row blocks enter already varying over ``axis``, so the
+            # scan carry types match without a cast.
+            (theta_l, pmat_l), outs = jax.lax.scan(
+                body, (theta_l, pmat_l), (xs, ys)
+            )
             return theta_l, pmat_l, step + xs.shape[0], outs
 
         in_specs = (
@@ -541,8 +548,6 @@ def _sharded_krls_run_program(
         def node(
             omega_l, bias_l, scale_l, theta_l, pmat_l, step, xs, ys, mask
         ):
-            carry0 = _mark_varying((theta_l, pmat_l), axis)
-
             def body(carry, xym):
                 th, pm = carry
                 xb, yb, mb = xym
@@ -553,7 +558,7 @@ def _sharded_krls_run_program(
                 return (th, pm), out
 
             (theta_l, pmat_l), outs = jax.lax.scan(
-                body, carry0, (xs, ys, mask)
+                body, (theta_l, pmat_l), (xs, ys, mask)
             )
             outs = jax.tree.map(lambda a: a.reshape(-1), outs)
             ticks = jnp.sum(mask).astype(step.dtype)
@@ -565,7 +570,7 @@ def _sharded_krls_run_program(
             P(), P(), P(),
         )
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         node,
         mesh=mesh,
         in_specs=in_specs,

@@ -41,6 +41,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import F32_DOT
+
 __all__ = [
     "RFF",
     "sample_rff",
@@ -128,13 +130,13 @@ def rff_features(rff: RFF, x: jax.Array) -> jax.Array:
       features ``(..., D)`` such that ``z(x) @ z(y) ~= kappa(x - y)``.
     """
     d = rff.num_features
-    proj = x @ rff.omega + rff.bias
+    proj = jnp.matmul(x, rff.omega, precision=F32_DOT) + rff.bias
     return jnp.sqrt(2.0 / d).astype(proj.dtype) * jnp.cos(proj)
 
 
 def rff_features_unscaled(rff: RFF, x: jax.Array) -> jax.Array:
     """``sqrt(2) cos(x @ omega + b)`` — per-feature form of Theorem 1."""
-    proj = x @ rff.omega + rff.bias
+    proj = jnp.matmul(x, rff.omega, precision=F32_DOT) + rff.bias
     return jnp.sqrt(2.0).astype(proj.dtype) * jnp.cos(proj)
 
 

@@ -41,6 +41,8 @@ from typing import TYPE_CHECKING, Any, Callable, NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import F32_DOT
+
 if TYPE_CHECKING:  # runtime import is lazy: core.klms/krls import this module
     from repro.core.rff import RFF
 
@@ -120,7 +122,7 @@ def trig_from_rff(rff: "RFF") -> TrigFeatures:
 
 def trig_features(tf: TrigFeatures, x: jax.Array) -> jax.Array:
     """``z(x) = scale * cos(x @ omega + bias)`` — inputs ``(..., d)``."""
-    proj = x @ tf.omega + tf.bias
+    proj = jnp.matmul(x, tf.omega, precision=F32_DOT) + tf.bias
     return tf.scale.astype(proj.dtype) * jnp.cos(proj)
 
 

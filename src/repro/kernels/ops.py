@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels, with backend dispatch.
 
-On TPU the compiled Pallas kernels run natively; on CPU (this container) the
-default is the pure-XLA reference path, with ``interpret=True`` Pallas
-execution available for kernel-correctness tests. The API is stable across
-backends so the model code never branches.
+On TPU the compiled Pallas kernels run natively; on CPU the default is the
+pure-XLA reference path, and ``mode="interpret"`` runs the Pallas kernels in
+the interpreter for kernel-correctness tests. ``mode="pallas"`` demands the
+compiled kernels and raises off a TPU rather than quietly interpreting. The
+API is stable across backends so the model code never branches.
 
 Dispatch observability: the serving-path ops are host wrappers around
 their jitted cores. Each call reports to ``repro.obs.telemetry`` (live
@@ -80,13 +81,21 @@ def _use_pallas(mode: str) -> tuple[bool, bool]:
 
     ``fused`` / ``twopass`` are aliases for ``pallas`` / ``xla``: the fused
     single-program path vs the two-pass reference (feature map and update as
-    separate passes with an HBM round-trip between them).
+    separate passes with an HBM round-trip between them). ``pallas`` off a
+    TPU is an error: a run that asked for the compiled kernels must not
+    measure the interpreter instead.
     """
     if mode == "auto":
         on_tpu = default_backend() == "tpu"
         return on_tpu, False
     if mode in ("pallas", "fused"):
-        return True, default_backend() != "tpu"
+        if default_backend() != "tpu":
+            raise RuntimeError(
+                f"mode={mode!r} needs a TPU, but the JAX backend is "
+                f"{default_backend()!r}; use mode='interpret' to run the "
+                "Pallas kernels in the interpreter"
+            )
+        return True, False
     if mode == "interpret":
         return True, True
     if mode in ("xla", "twopass"):

@@ -4,9 +4,11 @@ Deliberately naive implementations — clarity over speed.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "F32_DOT",
     "canon_precision",
     "mp_project",
     "mp_trig",
@@ -46,6 +48,13 @@ __all__ = [
 _BF16 = ("bf16", "bfloat16")
 _F32 = (None, "f32", "float32")
 
+# The precision every f32 dot in the Pallas kernels, the featurize GEMM and
+# the dense and sharded KRLS recursions (core/krls.py) asks for. A TPU runs an
+# f32 matmul as one bf16 pass unless told otherwise, in Mosaic as in XLA:
+# on a v5e that moved the KLMS fleet's theta by ~1e-2 against the f32
+# reference (chip_smoke.py). CPU dots are f32 either way.
+F32_DOT = jax.lax.Precision.HIGHEST
+
 
 def canon_precision(precision):
     """Validate + canonicalize the knob: ``"bf16"`` or ``None`` (f32).
@@ -70,7 +79,7 @@ def mp_project(x, w, precision=None):
             w.astype(jnp.bfloat16),
             preferred_element_type=jnp.float32,
         )
-    return x @ w
+    return jnp.dot(x, w, precision=F32_DOT)
 
 
 def mp_trig(proj, b, s, precision=None):

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import canon_precision, mp_project, mp_trig
+from repro.kernels.ref import F32_DOT, canon_precision, mp_project, mp_trig
 
 __all__ = [
     "rff_attention_kernel",
@@ -165,6 +165,8 @@ def rff_attention_decode_block_kernel(
     o_ref,
     s_out_ref,
     z_out_ref,
+    phi_q_ref,
+    phi_k_ref,
     *,
     tlen: int,
     dfeat: int,
@@ -173,9 +175,8 @@ def rff_attention_decode_block_kernel(
     eps: float,
     precision,
 ):
-    q = q_ref[0].astype(jnp.float32)  # (Tp, dhp)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)  # (Tp, dvp)
+    q = q_ref[...].astype(jnp.float32)  # (Tp, dhp)
+    k = k_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)  # (dhp, Dp)
     bias = b_ref[...].astype(jnp.float32)  # (1, Dp)
     sc = sc_ref[...].astype(jnp.float32)  # (1, Dp); padded columns are 0
@@ -195,34 +196,38 @@ def rff_attention_decode_block_kernel(
                 phi = phi.astype(jnp.bfloat16)
         return phi.astype(jnp.float32)
 
-    phi_q = feat(q)  # (Tp, Dp)
-    phi_k = feat(k)
+    # Feature rows go through VMEM scratch: a tick reads its row from a ref
+    # (Mosaic has no dynamic slice of a value).
+    phi_q_ref[...] = feat(q)  # (Tp, Dp)
+    phi_k_ref[...] = feat(k)
 
     o_ref[...] = jnp.zeros_like(o_ref)
 
     def tick(i, carry):
         s_st, z_st = carry  # (Dp, dvp) f32, (1, Dp) f32
-        qt = jax.lax.dynamic_slice_in_dim(phi_q, i, 1, axis=0)  # (1, Dp)
-        kt = jax.lax.dynamic_slice_in_dim(phi_k, i, 1, axis=0)
-        vt = jax.lax.dynamic_slice_in_dim(v, i, 1, axis=0)  # (1, dvp)
+        qt = phi_q_ref[pl.ds(i, 1), :]  # (1, Dp)
+        kt = phi_k_ref[pl.ds(i, 1), :]
+        vt = v_ref[pl.ds(i, 1), :].astype(jnp.float32)  # (1, dvp)
         # Update BEFORE emitting — the token attends to itself (the
         # ops.rff_attention_decode contract).
         s_st = s_st + kt.T * vt  # rank-1, same elementwise order as oracle
         z_st = z_st + kt
-        num = jnp.dot(qt, s_st, preferred_element_type=jnp.float32)
+        num = jnp.dot(
+            qt, s_st, preferred_element_type=jnp.float32, precision=F32_DOT
+        )
         if normalize:
             den = jnp.sum(qt * z_st, axis=-1) + eps
             num = num / den[:, None]
-        o_ref[0, pl.ds(i, 1), :] = num.astype(o_ref.dtype)
+        o_ref[pl.ds(i, 1), :] = num.astype(o_ref.dtype)
         return s_st, z_st
 
     s_f, z_f = jax.lax.fori_loop(
         0,
         tlen,
         tick,
-        (s_in_ref[0].astype(jnp.float32), z_in_ref[...].astype(jnp.float32)),
+        (s_in_ref[...].astype(jnp.float32), z_in_ref[...].astype(jnp.float32)),
     )
-    s_out_ref[0] = s_f
+    s_out_ref[...] = s_f
     z_out_ref[...] = z_f
 
 
@@ -271,6 +276,7 @@ def rff_attention_decode_block_pallas(
     padded dv columns are sliced off.
     """
     from repro.kernels.ref import default_decode_scale
+    from repro.kernels.chunking import _lead_block
     from repro.kernels.rff_features import _ceil_to, _pad2
 
     precision = canon_precision(precision)
@@ -297,7 +303,11 @@ def rff_attention_decode_block_pallas(
         s_state.astype(jnp.float32),
         ((0, 0), (0, dp - dfeat), (0, dvp - dv)),
     )
-    zv_p = jnp.pad(z_state.astype(jnp.float32), ((0, 0), (0, dp - dfeat)))
+    # z rows as (BH, 1, Dp): a head's block then equals the array's last two
+    # dims (the TPU tiling rule).
+    zv_p = jnp.pad(z_state.astype(jnp.float32), ((0, 0), (0, dp - dfeat)))[
+        :, None, :
+    ]
 
     out, s_new, z_new = pl.pallas_call(
         functools.partial(
@@ -311,29 +321,30 @@ def rff_attention_decode_block_pallas(
         ),
         grid=(bh,),
         in_specs=[
-            pl.BlockSpec((1, tp, dhp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, tp, dhp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, tp, dvp), lambda i: (i, 0, 0)),
+            _lead_block(tp, dhp),
+            _lead_block(tp, dhp),
+            _lead_block(tp, dvp),
             pl.BlockSpec((dhp, dp), lambda i: (0, 0)),  # grid-invariant W
             pl.BlockSpec((1, dp), lambda i: (0, 0)),
             pl.BlockSpec((1, dp), lambda i: (0, 0)),
-            pl.BlockSpec((1, dp, dvp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),
+            _lead_block(dp, dvp),
+            _lead_block(1, dp),
         ],
         out_specs=[
-            pl.BlockSpec((1, tp, dvp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, dp, dvp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),
+            _lead_block(tp, dvp),
+            _lead_block(dp, dvp),
+            _lead_block(1, dp),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tp, dvp), jnp.float32),
             jax.ShapeDtypeStruct((bh, dp, dvp), jnp.float32),
-            jax.ShapeDtypeStruct((bh, dp), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, dp), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((tp, dp), jnp.float32)] * 2,
         interpret=interpret,
     )(q_p, k_p, v_p, w_p, b_p, s_p, sm_p, zv_p)
     return (
         out[:, :tlen, :dv],
         s_new[:, :dfeat, :dv],
-        z_new[:, :dfeat],
+        z_new[:, 0, :dfeat],
     )

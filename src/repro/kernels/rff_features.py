@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import canon_precision
+from repro.kernels.ref import F32_DOT, canon_precision
 
 __all__ = ["rff_features_kernel", "rff_features_pallas"]
 
@@ -52,11 +52,13 @@ def rff_features_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    gemm_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
+    bf16 = precision == "bf16"
+    gemm_dtype = jnp.bfloat16 if bf16 else jnp.float32
     acc_ref[...] += jnp.dot(
         x_ref[...].astype(gemm_dtype),
         w_ref[...].astype(gemm_dtype),
         preferred_element_type=jnp.float32,
+        precision=None if bf16 else F32_DOT,
     )
 
     @pl.when(k == nk - 1)

@@ -41,6 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.chunking import _tick_get, _tick_put
+from repro.kernels.ref import F32_DOT
 from repro.kernels.rff_features import _ceil_to, _pad2
 
 __all__ = [
@@ -64,6 +66,7 @@ def rff_klms_step_kernel(
         x_ref[...].astype(jnp.float32),
         w_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=F32_DOT,
     ) + b_ref[...].astype(jnp.float32)
     z = s_ref[...].astype(jnp.float32) * jnp.cos(proj)  # (bb, D), VMEM-only
     theta = theta_ref[...].astype(jnp.float32)
@@ -172,6 +175,11 @@ def rff_klms_chunk_kernel(
 ):
     """Grid point (i, t): tick t for bank block i on the resident theta tile.
 
+    ``x_ref`` is tick t's ``(bb, d)`` slab of the time-major inputs; the
+    ``(bb, T)`` target/mask/prediction/error tiles stay resident for the
+    whole chunk and column t is selected in-kernel (``_tick_get``/
+    ``_tick_put``).
+
     ``mask`` (0/1 per (filter, tick)) gates the update only — masked ticks
     still emit their prior prediction/error but leave theta untouched. With
     mask==1 the update expression multiplies by exactly 1.0, so an unmasked
@@ -189,18 +197,19 @@ def rff_klms_chunk_kernel(
         acc_ref[...] = theta_ref[...].astype(jnp.float32)
 
     proj = jnp.dot(
-        x_ref[:, 0, :].astype(jnp.float32),
+        x_ref[...].astype(jnp.float32),
         w_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=F32_DOT,
     ) + b_ref[...].astype(jnp.float32)
     z = s_ref[...].astype(jnp.float32) * jnp.cos(proj)  # (bb, D), VMEM-only
     theta = acc_ref[...]
     pred = jnp.sum(theta * z, axis=1, keepdims=True)  # (bb, 1)
-    err = y_ref[...].astype(jnp.float32) - pred
-    gated = mask_ref[...].astype(jnp.float32) * err
+    err = _tick_get(y_ref, t).astype(jnp.float32) - pred
+    gated = _tick_get(mask_ref, t).astype(jnp.float32) * err
     acc_ref[...] = theta + mu_ref[...].astype(jnp.float32) * gated * z
-    pred_ref[...] = pred.astype(pred_ref.dtype)
-    err_ref[...] = err.astype(err_ref.dtype)
+    _tick_put(pred_ref, t, pred)
+    _tick_put(err_ref, t, err)
 
     @pl.when(t == nt - 1)
     def _writeback():
@@ -254,7 +263,8 @@ def rff_klms_bank_chunk_pallas(
     if mask is None:
         mask = jnp.ones((bsz, tlen), theta.dtype)
     theta_p = _pad2(theta, bp, np_)
-    xs_p = jnp.pad(xs, ((0, bp - bsz), (0, 0), (0, dp - d)))
+    # Time-major (T, Bp, dp): tick t of bank block i is one (bb, dp) tile.
+    xs_p = jnp.swapaxes(jnp.pad(xs, ((0, bp - bsz), (0, 0), (0, dp - d))), 0, 1)
     ys_p = jnp.pad(ys, ((0, bp - bsz), (0, 0)))
     mask_p = jnp.pad(mask.astype(theta.dtype), ((0, bp - bsz), (0, 0)))
     mu_p = jnp.pad(mu_col, (0, bp - bsz))[:, None]
@@ -267,19 +277,19 @@ def rff_klms_bank_chunk_pallas(
         rff_klms_chunk_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bb, 1, dp), lambda i, t: (i, t, 0)),
+            pl.BlockSpec((None, bb, dp), lambda i, t: (t, i, 0)),
             pl.BlockSpec((dp, np_), lambda i, t: (0, 0)),  # grid-invariant W
             pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
             pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
             pl.BlockSpec((bb, np_), lambda i, t: (i, 0)),
-            pl.BlockSpec((bb, 1), lambda i, t: (i, t)),
+            pl.BlockSpec((bb, tlen), lambda i, t: (i, 0)),  # resident ticks
             pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec((bb, 1), lambda i, t: (i, t)),
+            pl.BlockSpec((bb, tlen), lambda i, t: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bb, np_), lambda i, t: (i, 0)),  # revisited over t
-            pl.BlockSpec((bb, 1), lambda i, t: (i, t)),
-            pl.BlockSpec((bb, 1), lambda i, t: (i, t)),
+            pl.BlockSpec((bb, tlen), lambda i, t: (i, 0)),
+            pl.BlockSpec((bb, tlen), lambda i, t: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bp, np_), theta.dtype),

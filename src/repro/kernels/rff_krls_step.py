@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.chunking import _lead_block, _tick_get, _tick_put, vmem_params
+from repro.kernels.ref import F32_DOT
 from repro.kernels.rff_features import _ceil_to, _pad2
 
 __all__ = [
@@ -71,6 +73,7 @@ def rff_krls_step_kernel(
         x_ref[...].astype(f32),
         w_ref[...].astype(f32),
         preferred_element_type=f32,
+        precision=F32_DOT,
     ) + b_ref[...].astype(f32)
     z = s_ref[...].astype(f32) * jnp.cos(proj)  # (1, D), VMEM-only
     theta = theta_ref[...].astype(f32)  # (1, D)
@@ -78,11 +81,12 @@ def rff_krls_step_kernel(
     err = y_ref[...].astype(f32) - pred
     beta = beta_ref[...].astype(f32)  # (1, 1)
 
-    p = p_ref[0].astype(f32)  # (D, D)
+    p = p_ref[...].astype(f32)  # (D, D)
     # pz[j] = sum_k P[j, k] z[k] — contract z's feature dim with P's column
     # dim; stays a (1, D) row so no relayout is needed.
     pz = jax.lax.dot_general(
-        z, p, (((1,), (1,)), ((), ())), preferred_element_type=f32
+        z, p, (((1,), (1,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (1, D)
     denom = beta + jnp.sum(z * pz, axis=1, keepdims=True)  # (1, 1)
     gain = pz / denom  # (1, D)
@@ -90,12 +94,13 @@ def rff_krls_step_kernel(
 
     # outer(g, pz): contract the unit leading dims — an MXU (D,1)@(1,D).
     outer = jax.lax.dot_general(
-        gain, pz, (((0,), (0,)), ((), ())), preferred_element_type=f32
+        gain, pz, (((0,), (0,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (D, D)
     p_new = (p - outer) / beta
     # Same numerical hygiene as the dense path: symmetrize to fight drift.
     p_new = 0.5 * (p_new + p_new.T)
-    p_out_ref[0] = p_new.astype(p_out_ref.dtype)
+    p_out_ref[...] = p_new.astype(p_out_ref.dtype)
     pred_ref[...] = pred.astype(pred_ref.dtype)
     err_ref[...] = err.astype(err_ref.dtype)
 
@@ -142,50 +147,55 @@ def rff_krls_bank_step_pallas(
     dp, np_ = _ceil_to(d, 128), _ceil_to(dfeat, 128)
     beta_col = jnp.broadcast_to(jnp.asarray(beta, theta.dtype), (bsz,))
 
-    theta_p = _pad2(theta, bsz, np_)
+    # Per-tenant rows as (B, 1, N): each block is one tenant's (1, N) row,
+    # whose last two dims equal the array's (the TPU tiling rule).
+    theta_p = _pad2(theta, bsz, np_)[:, None, :]
     p_p = jnp.pad(
         pmat, ((0, 0), (0, np_ - dfeat), (0, np_ - dfeat))
     )
-    x_p = _pad2(x, bsz, dp)
-    y_p = y[:, None]  # (B, 1)
-    beta_p = beta_col[:, None]
+    x_p = _pad2(x, bsz, dp)[:, None, :]
+    y_p = y[:, None, None]  # (B, 1, 1)
+    beta_p = beta_col[:, None, None]
     w_p = _pad2(w, dp, np_)
     b_p = jnp.pad(b, (0, np_ - dfeat))[None, :]  # (1, Np)
     s_p = jnp.pad(s, (0, np_ - dfeat))[None, :]  # (1, Np), padded scales 0
 
-    grid = (bsz,)
+    in_specs = [
+        _lead_block(1, dp),
+        pl.BlockSpec((dp, np_), lambda i: (0, 0)),  # grid-invariant W
+        pl.BlockSpec((1, np_), lambda i: (0, 0)),
+        pl.BlockSpec((1, np_), lambda i: (0, 0)),
+        _lead_block(1, np_),
+        _lead_block(np_, np_),
+        _lead_block(1, 1),
+        _lead_block(1, 1),
+    ]
+    out_specs = [
+        _lead_block(1, np_),
+        _lead_block(np_, np_),
+        _lead_block(1, 1),
+        _lead_block(1, 1),
+    ]
     theta_new, p_new, pred, err = pl.pallas_call(
         rff_krls_step_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),
-            pl.BlockSpec((dp, np_), lambda i: (0, 0)),  # grid-invariant W
-            pl.BlockSpec((1, np_), lambda i: (0, 0)),
-            pl.BlockSpec((1, np_), lambda i: (0, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_, np_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_, np_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        grid=(bsz,),
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, np_), theta.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, np_), theta.dtype),
             jax.ShapeDtypeStruct((bsz, np_, np_), pmat.dtype),
-            jax.ShapeDtypeStruct((bsz, 1), theta.dtype),
-            jax.ShapeDtypeStruct((bsz, 1), theta.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, 1), theta.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, 1), theta.dtype),
         ],
+        # One (D, D) temporary besides the blocks: the rank-1 downdate.
+        compiler_params=vmem_params(in_specs + out_specs, temps=[(np_, np_)]),
         interpret=interpret,
     )(x_p, w_p, b_p, s_p, theta_p, p_p, y_p, beta_p)
     return (
-        theta_new[:, :dfeat],
+        theta_new[:, 0, :dfeat],
         p_new[:, :dfeat, :dfeat],
-        pred[:, 0],
-        err[:, 0],
+        pred[:, 0, 0],
+        err[:, 0, 0],
     )
 
 
@@ -221,41 +231,44 @@ def rff_krls_chunk_kernel(
     @pl.when(t == 0)
     def _seed():
         th_acc[...] = theta_ref[...].astype(f32)
-        p_acc[...] = p_ref[0].astype(f32)
+        p_acc[...] = p_ref[...].astype(f32)
 
     proj = jnp.dot(
-        x_ref[:, 0, :].astype(f32),
+        x_ref[pl.ds(t, 1), :].astype(f32),  # tick t of the resident (T, d)
         w_ref[...].astype(f32),
         preferred_element_type=f32,
+        precision=F32_DOT,
     ) + b_ref[...].astype(f32)
     z = s_ref[...].astype(f32) * jnp.cos(proj)  # (1, D), VMEM-only
     theta = th_acc[...]  # (1, D)
     pred = jnp.sum(theta * z, axis=1, keepdims=True)  # (1, 1)
-    err = y_ref[...].astype(f32) - pred
+    err = _tick_get(y_ref, t).astype(f32) - pred
     beta = beta_ref[...].astype(f32)  # (1, 1)
-    m = mask_ref[...].astype(f32)  # (1, 1)
+    m = _tick_get(mask_ref, t).astype(f32)  # (1, 1)
 
     p = p_acc[...]  # (D, D) — resident across the chunk
     pz = jax.lax.dot_general(
-        z, p, (((1,), (1,)), ((), ())), preferred_element_type=f32
+        z, p, (((1,), (1,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (1, D)
     denom = beta + jnp.sum(z * pz, axis=1, keepdims=True)  # (1, 1)
     gain = pz / denom  # (1, D)
     th_acc[...] = theta + gain * (m * err)
 
     outer = jax.lax.dot_general(
-        gain, pz, (((0,), (0,)), ((), ())), preferred_element_type=f32
+        gain, pz, (((0,), (0,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (D, D)
     p_new = (p - outer) / beta
     p_new = 0.5 * (p_new + p_new.T)
     p_acc[...] = jnp.where(m[0, 0] > 0, p_new, p)
-    pred_ref[...] = pred.astype(pred_ref.dtype)
-    err_ref[...] = err.astype(err_ref.dtype)
+    _tick_put(pred_ref, t, pred)
+    _tick_put(err_ref, t, err)
 
     @pl.when(t == nt - 1)
     def _writeback():
         theta_out_ref[...] = th_acc[...].astype(theta_out_ref.dtype)
-        p_out_ref[0] = p_acc[...].astype(p_out_ref.dtype)
+        p_out_ref[...] = p_acc[...].astype(p_out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -304,51 +317,58 @@ def rff_krls_bank_chunk_pallas(
     if mask is None:
         mask = jnp.ones((bsz, tlen), theta.dtype)
 
-    theta_p = _pad2(theta, bsz, np_)
+    # Per-tenant rows as (B, 1, N) and the tenant's whole (T, d) input block
+    # resident across the chunk: every block's last two dims equal the
+    # array's (the TPU tiling rule for one-tenant blocks).
+    theta_p = _pad2(theta, bsz, np_)[:, None, :]
     p_p = jnp.pad(pmat, ((0, 0), (0, np_ - dfeat), (0, np_ - dfeat)))
     xs_p = jnp.pad(xs, ((0, 0), (0, 0), (0, dp - d)))
-    beta_p = beta_col[:, None]
-    mask_p = mask.astype(theta.dtype)
+    ys_p = ys[:, None, :]  # (B, 1, T)
+    beta_p = beta_col[:, None, None]
+    mask_p = mask.astype(theta.dtype)[:, None, :]
     w_p = _pad2(w, dp, np_)
     b_p = jnp.pad(b, (0, np_ - dfeat))[None, :]  # (1, Np)
     s_p = jnp.pad(s, (0, np_ - dfeat))[None, :]  # (1, Np), padded scales 0
 
-    grid = (bsz, tlen)  # t minor: theta/P tiles resident across the chunk
+    in_specs = [
+        _lead_block(tlen, dp),
+        pl.BlockSpec((dp, np_), lambda i, t: (0, 0)),  # grid-invariant W
+        pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
+        pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
+        _lead_block(1, np_),
+        _lead_block(np_, np_),
+        _lead_block(1, tlen),
+        _lead_block(1, 1),
+        _lead_block(1, tlen),
+    ]
+    out_specs = [  # all revisited over t, written back once per tenant
+        _lead_block(1, np_),
+        _lead_block(np_, np_),
+        _lead_block(1, tlen),
+        _lead_block(1, tlen),
+    ]
+    scratch = [pltpu.VMEM((1, np_), jnp.float32), pltpu.VMEM((np_, np_), jnp.float32)]
     theta_new, p_new, pred, err = pl.pallas_call(
         rff_krls_chunk_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, dp), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((dp, np_), lambda i, t: (0, 0)),  # grid-invariant W
-            pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, np_), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, np_, np_), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, np_), lambda i, t: (i, 0)),  # revisited over t
-            pl.BlockSpec((1, np_, np_), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-        ],
+        grid=(bsz, tlen),  # t minor: theta/P tiles resident across the chunk
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, np_), theta.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, np_), theta.dtype),
             jax.ShapeDtypeStruct((bsz, np_, np_), pmat.dtype),
-            jax.ShapeDtypeStruct((bsz, tlen), theta.dtype),
-            jax.ShapeDtypeStruct((bsz, tlen), theta.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, tlen), theta.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, tlen), theta.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((1, np_), jnp.float32),
-            pltpu.VMEM((np_, np_), jnp.float32),
-        ],
+        scratch_shapes=scratch,
+        # One (D, D) temporary besides blocks and scratch: the downdate.
+        compiler_params=vmem_params(
+            in_specs + out_specs, scratch, temps=[(np_, np_)]
+        ),
         interpret=interpret,
-    )(xs_p, w_p, b_p, s_p, theta_p, p_p, ys, beta_p, mask_p)
+    )(xs_p, w_p, b_p, s_p, theta_p, p_p, ys_p, beta_p, mask_p)
     return (
-        theta_new[:, :dfeat],
+        theta_new[:, 0, :dfeat],
         p_new[:, :dfeat, :dfeat],
-        pred,
-        err,
+        pred[:, 0],
+        err[:, 0],
     )

@@ -46,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.chunking import _lead_block, _tick_get, vmem_params
+from repro.kernels.ref import F32_DOT
 from repro.kernels.rff_features import _ceil_to, _pad2
 
 __all__ = [
@@ -54,6 +56,36 @@ __all__ = [
     "rff_krls_elements_kernel",
     "rff_krls_chunk_elements_pallas",
 ]
+
+
+def _element_call(kernel, nc, tlen, dp, np_, out_specs, out_shape, scratch,
+                  interpret):
+    """The ``(nc, Tc)`` pallas_call both element kernels share: inputs
+    ``xs (nc, Tc, d)``, W, b, s, ``ys (nc, 1, Tc)``, a (1, 1) scalar and
+    ``mask (nc, 1, Tc)``. The tick axis is minor, so chunk i's slabs and
+    its element accumulators stay resident across its ticks."""
+    in_specs = [
+        _lead_block(tlen, dp),
+        pl.BlockSpec((dp, np_), lambda i, t: (0, 0)),  # grid-invariant W
+        pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
+        pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
+        _lead_block(1, tlen),
+        pl.BlockSpec((1, 1), lambda i, t: (0, 0)),
+        _lead_block(1, tlen),
+    ]
+    return pl.pallas_call(
+        kernel,
+        grid=(nc, tlen),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        # One (D, D) temporary besides blocks and scratch: the outer product.
+        compiler_params=vmem_params(
+            in_specs + out_specs, scratch, temps=[(np_, np_)]
+        ),
+        interpret=interpret,
+    )
 
 
 def rff_klms_elements_kernel(
@@ -78,34 +110,39 @@ def rff_klms_elements_kernel(
         v_acc[...] = jnp.zeros_like(v_acc)
 
     proj = jnp.dot(
-        x_ref[:, 0, :].astype(f32),
+        x_ref[pl.ds(t, 1), :].astype(f32),  # tick t of the resident (Tc, d)
         w_ref[...].astype(f32),
         preferred_element_type=f32,
+        precision=F32_DOT,
     ) + b_ref[...].astype(f32)
     z = s_ref[...].astype(f32) * jnp.cos(proj)  # (1, D), VMEM-only
     mu = mu_ref[...].astype(f32)  # (1, 1)
     if normalized:
         mu = mu / (eps + jnp.sum(z * z, axis=1, keepdims=True))
-    mu_eff = mask_ref[...].astype(f32) * mu  # (1, 1); masked tick -> 0
+    mu_eff = _tick_get(mask_ref, t).astype(f32) * mu  # (1, 1); masked -> 0
 
     a = a_acc[...]  # (D, D) — resident across the chunk
     # row = z A: contract z's feature dim with A's row dim (MXU matvec).
     row = jax.lax.dot_general(
-        z, a, (((1,), (0,)), ((), ())), preferred_element_type=f32
+        z, a, (((1,), (0,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (1, D)
     # outer(z, row): contract the unit leading dims — an MXU (D,1)@(1,D).
     outer = jax.lax.dot_general(
-        z, row, (((0,), (0,)), ((), ())), preferred_element_type=f32
+        z, row, (((0,), (0,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (D, D)
-    a_acc[...] = a - mu_eff * outer
+    # Scale by the scalar, not the (1, 1) value: Mosaic cannot broadcast a
+    # vector over both tile dims.
+    a_acc[...] = a - mu_eff[0, 0] * outer
 
     v = v_acc[...]  # (1, D)
     zdotv = jnp.sum(z * v, axis=1, keepdims=True)  # (1, 1)
-    v_acc[...] = v - mu_eff * (zdotv - y_ref[...].astype(f32)) * z
+    v_acc[...] = v - mu_eff * (zdotv - _tick_get(y_ref, t).astype(f32)) * z
 
     @pl.when(t == nt - 1)
     def _writeback():
-        a_out_ref[0] = a_acc[...].astype(a_out_ref.dtype)
+        a_out_ref[...] = a_acc[...].astype(a_out_ref.dtype)
         v_out_ref[...] = v_acc[...].astype(v_out_ref.dtype)
 
 
@@ -159,39 +196,25 @@ def rff_klms_chunk_elements_pallas(
     b_p = jnp.pad(b, (0, np_ - dfeat))[None, :]  # (1, Np)
     s_p = jnp.pad(s, (0, np_ - dfeat))[None, :]  # (1, Np), padded scales 0
     mu_p = jnp.broadcast_to(jnp.asarray(mu, jnp.float32), (1, 1))
-    mask_p = mask.astype(jnp.float32)
+    mask_p = mask.astype(jnp.float32)[:, None, :]  # (nc, 1, Tc)
 
-    grid = (nc, tlen)  # t minor: element tiles resident across the chunk
     kernel = functools.partial(
         rff_klms_elements_kernel, normalized=normalized, eps=eps
     )
-    a, v = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, dp), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((dp, np_), lambda i, t: (0, 0)),  # grid-invariant W
-            pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, np_, np_), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, np_), lambda i, t: (i, 0)),
-        ],
+    a, v = _element_call(
+        kernel, nc, tlen, dp, np_,
+        out_specs=[_lead_block(np_, np_), _lead_block(1, np_)],
         out_shape=[
             jax.ShapeDtypeStruct((nc, np_, np_), jnp.float32),
-            jax.ShapeDtypeStruct((nc, np_), jnp.float32),
+            jax.ShapeDtypeStruct((nc, 1, np_), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch=[
             pltpu.VMEM((np_, np_), jnp.float32),
             pltpu.VMEM((1, np_), jnp.float32),
         ],
         interpret=interpret,
-    )(xs_p, w_p, b_p, s_p, ys, mu_p, mask_p)
-    return a[:, :dfeat, :dfeat], v[:, :dfeat]
+    )(xs_p, w_p, b_p, s_p, ys[:, None, :], mu_p, mask_p)
+    return a[:, :dfeat, :dfeat], v[:, 0, :dfeat]
 
 
 def rff_krls_elements_kernel(
@@ -214,26 +237,30 @@ def rff_krls_elements_kernel(
         r_acc[...] = jnp.zeros_like(r_acc)
 
     proj = jnp.dot(
-        x_ref[:, 0, :].astype(f32),
+        x_ref[pl.ds(t, 1), :].astype(f32),  # tick t of the resident (Tc, d)
         w_ref[...].astype(f32),
         preferred_element_type=f32,
+        precision=F32_DOT,
     ) + b_ref[...].astype(f32)
     z = s_ref[...].astype(f32) * jnp.cos(proj)  # (1, D), VMEM-only
-    m = mask_ref[...].astype(f32)  # (1, 1)
+    m = _tick_get(mask_ref, t).astype(f32)  # (1, 1)
     beta_eff = jnp.where(m > 0, beta_ref[...].astype(f32), 1.0)  # (1, 1)
 
     # outer(z, z): contract the unit leading dims — an MXU (D,1)@(1,D).
     outer = jax.lax.dot_general(
-        z, z, (((0,), (0,)), ((), ())), preferred_element_type=f32
+        z, z, (((0,), (0,)), ((), ())), preferred_element_type=f32,
+        precision=F32_DOT,
     )  # (D, D)
     g_acc[...] = g_acc[...] * beta_eff
-    phi_acc[...] = beta_eff * phi_acc[...] + m * outer
-    r_acc[...] = beta_eff * r_acc[...] + (m * y_ref[...].astype(f32)) * z
+    # Scale by scalars, not (1, 1) values: Mosaic cannot broadcast a vector
+    # over both tile dims.
+    phi_acc[...] = beta_eff[0, 0] * phi_acc[...] + m[0, 0] * outer
+    r_acc[...] = beta_eff * r_acc[...] + (m * _tick_get(y_ref, t).astype(f32)) * z
 
     @pl.when(t == nt - 1)
     def _writeback():
         g_out_ref[...] = g_acc[...].astype(g_out_ref.dtype)
-        phi_out_ref[0] = phi_acc[...].astype(phi_out_ref.dtype)
+        phi_out_ref[...] = phi_acc[...].astype(phi_out_ref.dtype)
         r_out_ref[...] = r_acc[...].astype(r_out_ref.dtype)
 
 
@@ -274,36 +301,25 @@ def rff_krls_chunk_elements_pallas(
     b_p = jnp.pad(b, (0, np_ - dfeat))[None, :]  # (1, Np)
     s_p = jnp.pad(s, (0, np_ - dfeat))[None, :]  # (1, Np), padded scales 0
     beta_p = jnp.broadcast_to(jnp.asarray(beta, jnp.float32), (1, 1))
-    mask_p = mask.astype(jnp.float32)
+    mask_p = mask.astype(jnp.float32)[:, None, :]  # (nc, 1, Tc)
 
-    grid = (nc, tlen)  # t minor: element tiles resident across the chunk
-    g, phi, r = pl.pallas_call(
-        rff_krls_elements_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, dp), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((dp, np_), lambda i, t: (0, 0)),  # grid-invariant W
-            pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, np_), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, t)),
-        ],
+    g, phi, r = _element_call(
+        rff_krls_elements_kernel, nc, tlen, dp, np_,
         out_specs=[
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, np_, np_), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, np_), lambda i, t: (i, 0)),
+            _lead_block(1, 1),
+            _lead_block(np_, np_),
+            _lead_block(1, np_),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nc, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nc, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((nc, np_, np_), jnp.float32),
-            jax.ShapeDtypeStruct((nc, np_), jnp.float32),
+            jax.ShapeDtypeStruct((nc, 1, np_), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((np_, np_), jnp.float32),
             pltpu.VMEM((1, np_), jnp.float32),
         ],
         interpret=interpret,
-    )(xs_p, w_p, b_p, s_p, ys, beta_p, mask_p)
-    return g[:, 0], phi[:, :dfeat, :dfeat], r[:, :dfeat]
+    )(xs_p, w_p, b_p, s_p, ys[:, None, :], beta_p, mask_p)
+    return g[:, 0, 0], phi[:, :dfeat, :dfeat], r[:, 0, :dfeat]

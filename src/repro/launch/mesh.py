@@ -7,6 +7,12 @@ reduction (DCN), intra-pod is TP/EP/FSDP (ICI).
 
 Defined as functions so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every mesh here has ``Auto`` axes: ``jax.make_mesh`` defaults to
+``Explicit`` axes, which put the sharding into array types and refuse
+indexing whose output sharding is ambiguous (``theta[0:1]`` of a
+node-sharded array). The repo's programs place arrays with explicit
+``NamedSharding``s and ``shard_map`` specs instead.
 """
 from __future__ import annotations
 
@@ -15,6 +21,7 @@ import jax
 from repro.core.krls import KRLS_SHARD_AXIS
 
 __all__ = [
+    "make_mesh",
     "make_production_mesh",
     "make_krls_mesh",
     "data_axes",
@@ -26,10 +33,16 @@ __all__ = [
 MODEL_AXIS = "model"
 
 
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_krls_mesh(n_shards: int | None = None) -> jax.sharding.Mesh:
@@ -40,7 +53,7 @@ def make_krls_mesh(n_shards: int | None = None) -> jax.sharding.Mesh:
     use (the pattern tests/test_krls_sharded.py runs in a subprocess).
     """
     n = n_shards if n_shards is not None else jax.device_count()
-    return jax.make_mesh((n,), (KRLS_SHARD_AXIS,))
+    return make_mesh((n,), (KRLS_SHARD_AXIS,))
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -349,13 +349,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
 from repro.core.krls import rff_krls_run, sharded_krls_run
 from repro.core.rff import sample_rff
+from repro.launch.mesh import make_krls_mesh
 from repro.data.synthetic import gen_nonlinear_wiener
 
 res = {}
 xs64, ys64 = gen_nonlinear_wiener(jax.random.PRNGKey(1), num_samples=300)
 xs, ys = xs64.astype(jnp.float32), ys64.astype(jnp.float32)
 rff = sample_rff(jax.random.PRNGKey(0), 5, 256, sigma=5.0)
-mesh = jax.make_mesh((8,), ("shard",))
+mesh = make_krls_mesh(8)
 
 _, tick = sharded_krls_run(mesh, rff, xs, ys, lam=1e-2, beta=0.9995)
 _, dense = rff_krls_run(rff, xs, ys, lam=1e-2, beta=0.9995)

@@ -25,7 +25,8 @@ from repro.core.distributed import diffusion_klms_run
 from repro.core.rff import sample_rff
 from repro.data.synthetic import gen_nonlinear_wiener
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 rff = sample_rff(jax.random.PRNGKey(0), 5, 100, sigma=5.0)
 nodes = 8
 # ONE underlying system, split into per-node streams (the diffusion setting:

@@ -8,6 +8,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data.lm_data import batch_at_step
 from repro.train import checkpoint as ckpt
+from repro.launch.mesh import make_mesh
 from repro.train.elastic import remesh
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -106,7 +107,7 @@ def test_straggler_watchdog_detects_slow_steps(tmp_path):
 def test_elastic_remesh_preserves_values(key):
     """Re-sharding to a new (here: same-size) mesh preserves the state."""
     state = {"w": jax.random.normal(key, (8, 8))}
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shard = jax.tree.map(
         lambda _: jax.NamedSharding(mesh, jax.sharding.PartitionSpec()), state
     )

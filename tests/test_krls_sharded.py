@@ -30,6 +30,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
 from repro.core.krls import rff_krls_run, sharded_krls_run
 from repro.core.learner import krls_learner, sharded_krls_learner
+from repro.launch.mesh import make_krls_mesh
 from repro.data.synthetic import gen_nonlinear_wiener
 from repro.core.rff import sample_rff
 
@@ -42,7 +43,7 @@ rff = sample_rff(jax.random.PRNGKey(0), 5, 256, sigma=5.0)
 # f32, well-conditioned regularizer, 600 ticks, every shard count that
 # divides the 8 host devices.
 for n in (2, 4, 8):
-    mesh = jax.make_mesh((n,), ("shard",))
+    mesh = make_krls_mesh(n)
     _, dense = rff_krls_run(rff, xs, ys, lam=1e-2, beta=0.9995)
     _, shard = sharded_krls_run(mesh, rff, xs, ys, lam=1e-2, beta=0.9995)
     res[f"f32_pred_maxdiff_n{n}"] = float(
@@ -51,7 +52,7 @@ for n in (2, 4, 8):
 # f64 at the paper's hyperparams (lam=1e-4, beta=0.9995): the sharded
 # restructuring is exact math, so the gap is pure reduction-order noise.
 if jax.config.jax_enable_x64:
-    mesh = jax.make_mesh((8,), ("shard",))
+    mesh = make_krls_mesh(8)
     rff64 = sample_rff(jax.random.PRNGKey(0), 5, 256, sigma=5.0,
                        dtype=jnp.float64)
     _, dense = rff_krls_run(rff64, xs64, ys64, lam=1e-4, beta=0.9995)
@@ -61,7 +62,7 @@ if jax.config.jax_enable_x64:
         jnp.max(jnp.abs(dense.prediction - shard.prediction)))
 
 # the OnlineLearner adapter: per-tick step fn + predict fn
-mesh = jax.make_mesh((8,), ("shard",))
+mesh = make_krls_mesh(8)
 ls = sharded_krls_learner(mesh, rff, lam=1e-2, beta=0.9995)
 ld = krls_learner(rff, lam=1e-2, beta=0.9995)
 ss, sd = ls.init(), ld.init()

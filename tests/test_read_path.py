@@ -181,14 +181,17 @@ def test_default_chunk_t_corners():
     assert default_chunk_t(8, 512, pmat=True) == 512
     # ...but a huge-D P busts the budget entirely -> the dispatch floor.
     assert default_chunk_t(8, 1408, pmat=True) == 8
-    # Tighter budget exercises the power-of-two floor between the clamps.
-    assert default_chunk_t(16, 256, vmem_budget=2**20) == 128
+    # Tighter budget exercises the power-of-two floor between the clamps
+    # (the resident state and W tile take ~344 KiB of it).
+    assert default_chunk_t(16, 256, vmem_budget=450_000) == 256
     # f64 streams halve the tick count before clamping.
-    assert default_chunk_t(16, 256, jnp.float64, vmem_budget=2**20) == 64
-    # A wide input dim is charged at its real lane-padded width: the W
-    # tile and x streams shrink the budget (vs the low-d default of one
-    # lane tile, which would still pick 512 here).
-    assert default_chunk_t(16, 2048, input_dim=512) == 128
+    assert default_chunk_t(16, 256, jnp.float64, vmem_budget=450_000) == 128
+    # A wide input dim is charged at its real lane-padded width: the KRLS
+    # chunk kernel keeps the tenant's whole (T, d) input slab resident, so
+    # it shrinks T (vs the low-d default of one lane tile, which would
+    # still pick 512 here).
+    assert default_chunk_t(1, 512, pmat=True, input_dim=2048) == 32
+    assert default_chunk_t(1, 512, pmat=True) == 512
     # Everything stays inside the documented clamp range.
     for bank in (1, 8, 64):
         for dfeat in (17, 128, 2048):
