@@ -2,9 +2,11 @@
 
 The serving stack (facade -> queue -> snapshot -> kernel dispatch) is
 host-side and synchronous, so a plain span stack gives an exact causal
-tree of every request: ``serve.submit`` contains ``queue.flush`` contains
-``snapshot.publish`` contains nothing, and the first flush additionally
-contains the trace-time ``kernel.*`` dispatch spans. This module is the
+tree of every request: ``serve.submit`` contains ``snapshot.watermark``
+and then ``queue.flush``, which contains its phases ``queue.batch``,
+``queue.launch``, ``queue.wait`` and ``queue.results``; the first
+flush's ``queue.launch`` additionally contains the trace-time
+``kernel.*`` dispatch spans. This module is the
 smallest tracer that supports that:
 
 * :class:`Tracer` — ``with tracer.span("serve.flush", tenant=3):``

@@ -449,8 +449,9 @@ class Server:
     Metrics (``self.metrics``): counters ``requests.write`` /
     ``requests.read`` / ``bank.hits`` / ``bank.misses`` / ``evictions`` /
     ``readmissions`` / ``admission.rejects`` / ``read.cold`` /
-    ``resizes``, gauge ``queue.backlog``, histograms ``latency.write_us``
-    / ``latency.read_us``.
+    ``resizes``, gauge ``queue.backlog`` (set when :meth:`observability`
+    exports, not per arrival), histograms ``latency.write_us`` /
+    ``latency.read_us``.
 
     Observability (``make_server(trace=..., probe=...)``): a Tracer
     records nested ``serve.*`` / ``queue.*`` / ``snapshot.*`` /
@@ -668,7 +669,12 @@ class Server:
 
         Stable schema (validated by scripts/check_bench_schema.py for the
         records the Zipf bench embeds); see README "Observability".
+        The ``queue.backlog`` gauge is set here, at export: summing the
+        backlog walks every slot, too dear for each arrival.
         """
+        self.metrics.set_gauge(
+            "queue.backlog", float(sum(self._inner.queue.backlog()))
+        )
         return {
             "metrics": self.metrics.snapshot(),
             "dispatch": _telemetry.snapshot(),
@@ -700,9 +706,6 @@ class Server:
             else:
                 self._policy_submit(tenant, x, y)
             self._probe_update()
-            self.metrics.set_gauge(
-                "queue.backlog", float(sum(self._inner.queue.backlog()))
-            )
             self.metrics.histogram("latency.write_us").observe(
                 (self._lat() - t0) * 1e6
             )

@@ -267,50 +267,61 @@ class MicroBatchQueue:
         if not any(self._pending):
             _trace.instant("queue.flush.skip", tenants=bsz)
             return {}
+        # The four phase spans cover the whole body, so they sum to the
+        # flush.
         with _trace.span(
             "queue.flush", tenants=bsz, chunk=tlen, adaptive=self.adaptive
         ) as sp:
-            xs = np.zeros((bsz, tlen, d), self._dtype)
-            ys = np.zeros((bsz, tlen), self._dtype)
-            mask = np.zeros((bsz, tlen), self._dtype)
-            counts = []
-            for b, q in enumerate(self._pending):
-                take = min(len(q), tlen)
-                for t in range(take):
-                    x, y = q.popleft()
-                    xs[b, t] = x
-                    ys[b, t] = y
-                    mask[b, t] = 1.0
-                counts.append(take)
-                if not q:
-                    self._first_pending_at[b] = None
-                # Residual backlog keeps its stamp: the surviving head is
-                # at least as old as the arrival that set it.
-            result = self._chunk_step(self.state, xs, ys, mask)
-            if len(result) == 3:
-                self.state, out, self.last_probe = result
-            else:
-                self.state, out = result
-            preds = np.asarray(out.prediction)
-            errs = np.asarray(out.error)
-            self.flushes += 1
-            served = sum(counts)
-            self.ticks_served += served
-            # One compiled-program execution per flush: the live launch
-            # count for the serve path (the in-program kernel dispatches
-            # were counted at trace time under kernel.traces).
-            _telemetry.registry().counter(
-                "dispatch.launches", site="queue.flush"
-            ).inc()
-            if sp is not None:
-                sp.attrs["ticks"] = served
-                sp.attrs["active"] = sum(1 for c in counts if c)
-                sp.attrs["residual_backlog"] = sum(self.backlog())
-            return {
-                b: [(float(preds[b, t]), float(errs[b, t])) for t in range(c)]
-                for b, c in enumerate(counts)
-                if c
-            }
+            with _trace.span("queue.batch"):
+                xs = np.zeros((bsz, tlen, d), self._dtype)
+                ys = np.zeros((bsz, tlen), self._dtype)
+                mask = np.zeros((bsz, tlen), self._dtype)
+                counts = []
+                for b, q in enumerate(self._pending):
+                    take = min(len(q), tlen)
+                    for t in range(take):
+                        x, y = q.popleft()
+                        xs[b, t] = x
+                        ys[b, t] = y
+                        mask[b, t] = 1.0
+                    counts.append(take)
+                    if not q:
+                        self._first_pending_at[b] = None
+                    # Residual backlog keeps its stamp: the surviving head
+                    # is at least as old as the arrival that set it.
+            with _trace.span(
+                "queue.launch", bytes=xs.nbytes + ys.nbytes + mask.nbytes
+            ):
+                result = self._chunk_step(self.state, xs, ys, mask)
+                if len(result) == 3:
+                    self.state, out, self.last_probe = result
+                else:
+                    self.state, out = result
+            with _trace.span("queue.wait"):
+                preds = np.asarray(out.prediction)
+                errs = np.asarray(out.error)
+            with _trace.span("queue.results"):
+                self.flushes += 1
+                served = sum(counts)
+                self.ticks_served += served
+                # One compiled-program execution per flush: the live launch
+                # count for the serve path (the in-program kernel
+                # dispatches were counted at trace time under
+                # kernel.traces).
+                _telemetry.registry().counter(
+                    "dispatch.launches", site="queue.flush"
+                ).inc()
+                if sp is not None:
+                    sp.attrs["ticks"] = served
+                    sp.attrs["active"] = sum(1 for c in counts if c)
+                return {
+                    b: [
+                        (float(preds[b, t]), float(errs[b, t]))
+                        for t in range(c)
+                    ]
+                    for b, c in enumerate(counts)
+                    if c
+                }
 
     def drain(self) -> dict[int, list[tuple[float, float]]]:
         """Flush until all backlogs are empty; merge per-tenant results."""

@@ -328,21 +328,28 @@ class SnapshotServer:
     def maybe_flush(self) -> dict:
         """Background-flush hook: flush when the age or size watermark
         trips. Call from an outer event loop for purely time-driven
-        flushes; ``submit`` calls it after every arrival."""
+        flushes; ``submit`` calls it after every arrival.
+
+        The ``snapshot.watermark`` span covers the test alone, so a flush
+        it triggers is a sibling span, not a child."""
+        with _trace.span("snapshot.watermark"):
+            due = self._watermark_due()
+        return self.flush() if due else {}
+
+    def _watermark_due(self) -> bool:
         backlog = self.queue.backlog()
         if not any(backlog):
-            return {}
+            return False
         if self.size_watermark is not None and max(backlog) >= self.size_watermark:
-            return self.flush()
+            return True
         if self.age_watermark is not None:
             oldest = min(
                 (t[0][1] for t in self._arrival_times if t), default=None
             )
-            if oldest is not None and (
+            return oldest is not None and (
                 self._clock() - oldest >= self.age_watermark
-            ):
-                return self.flush()
-        return {}
+            )
+        return False
 
     def flush(self) -> dict:
         """One chunked train launch on the live state; publish when due.

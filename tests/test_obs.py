@@ -19,7 +19,9 @@ Five families:
   + probed server is BITWISE state-identical to an untraced one on the
   same stream, its spans cover the serve tiers, its flush overhead stays
   within a pinned (generous) factor, and ``Server.observability()``
-  exports the documented schema.
+  exports the documented schema; the write path's phase spans nest as
+  the benchmark's readers assume (``queue.flush`` a direct child of
+  ``serve.submit``), and the backlog gauge is set at export.
 """
 import importlib.util
 import json
@@ -543,6 +545,115 @@ def test_traced_flush_overhead_within_pinned_factor():
     # Generous pin: spans + probe materialization must stay the same order
     # of magnitude as the flush itself, not multiply it.
     assert dt_traced < dt_plain * 20 + 0.05
+
+
+# ---------------------------------------------------------------------------
+# Write-path phase spans and the export-time backlog gauge
+# ---------------------------------------------------------------------------
+
+_PHASES = ["queue.batch", "queue.launch", "queue.wait", "queue.results"]
+_WRITE_CASES = [
+    ("klms", dict(mu=0.3)),
+    ("krls", dict(beta=0.999, lam=0.1)),
+]
+
+
+def _watermark_server(learner, hp, trace=None):
+    return api.make_server(
+        learner, feature_map=RFF, bank=3, chunk=4, size_watermark=4,
+        trace=trace, **hp,
+    )
+
+
+def _span_tree(spans, parent_id=None):
+    """Nested ``(name, children)`` of the completed spans, children in
+    start order; trace-time ``kernel.*`` spans (first call only) left
+    out."""
+    kids = sorted(
+        (s for s in spans
+         if s.kind == "span" and s.parent_id == parent_id
+         and not s.name.startswith("kernel.")),
+        key=lambda s: s.t0,
+    )
+    return [(s.name, _span_tree(spans, s.span_id)) for s in kids]
+
+
+@pytest.mark.parametrize("learner,hp", _WRITE_CASES)
+def test_write_path_phase_spans_nest_under_submit(learner, hp):
+    srv = _watermark_server(learner, hp, obs_trace.Tracer(clock=FakeClock()))
+    x = np.ones(D_IN, np.float32)
+    for _ in range(2):  # two size-watermark flushes, the second one warm
+        for i in range(4):
+            srv.submit(1, x * i, float(i))
+    spans = srv.tracer.spans()
+    quiet = ("serve.submit", [("snapshot.watermark", [])])
+    flushing = ("serve.submit", [
+        ("snapshot.watermark", []),
+        ("queue.flush", [(name, []) for name in _PHASES]),
+    ])
+    assert _span_tree(spans) == [quiet] * 3 + [flushing] + [quiet] * 3 + [
+        flushing]
+    by_id = {s.span_id: s for s in spans}
+    flushes = [s for s in spans if s.name == "queue.flush"]
+    assert len(flushes) == 2
+    for fl in flushes:
+        # A direct child of serve.submit: the watermark span closes before
+        # the flush it triggers opens.
+        assert by_id[fl.parent_id].name == "serve.submit"
+        assert set(fl.attrs) == {"tenants", "chunk", "adaptive", "ticks",
+                                 "active"}
+        assert fl.attrs["ticks"] == 4 and fl.attrs["active"] == 1
+        phases = sorted(
+            (s for s in spans if s.parent_id == fl.span_id),
+            key=lambda s: s.t0,
+        )
+        assert [s.name for s in phases] == _PHASES
+        assert fl.t0 < phases[0].t0
+        assert all(a.t1 < b.t0 for a, b in zip(phases, phases[1:]))
+        assert phases[-1].t1 < fl.t1
+        assert phases[1].attrs == {"bytes": 3 * 4 * (D_IN + 2) * 4}
+    for s in spans:
+        if s.name.startswith("kernel."):
+            assert by_id[s.parent_id].name == "queue.launch"
+
+
+@pytest.mark.parametrize("learner,hp", _WRITE_CASES)
+def test_phase_spans_leave_a_watermark_server_bitwise_unchanged(learner, hp):
+    traffic = ragged_traffic(tenants=3, n=30, seed=11)
+    plain = _watermark_server(learner, hp)
+    traced = _watermark_server(learner, hp, obs_trace.Tracer(clock=FakeClock()))
+    for srv in (plain, traced):
+        for t, x, y in traffic:
+            srv.submit(t, x, y)
+    assert plain.queue.flushes == traced.queue.flushes > 0
+    assert plain.queue.backlog() == traced.queue.backlog()
+    assert_trees_bitwise(plain.queue.state, traced.queue.state)
+    assert_trees_bitwise(plain.snapshot.state, traced.snapshot.state)
+    plain.drain()
+    traced.drain()
+    assert_trees_bitwise(plain.queue.state, traced.queue.state)
+
+
+@pytest.mark.parametrize("learner,hp", _WRITE_CASES)
+def test_backlog_gauge_is_set_at_export_not_per_arrival(learner, hp):
+    srv = _watermark_server(learner, hp)
+    x = np.ones(D_IN, np.float32)
+
+    def exported():
+        return srv.observability()["metrics"]["gauges"]["queue.backlog"]
+
+    for t in (0, 1, 1, 2, 2, 2):
+        srv.submit(t, x, 1.0)
+    assert srv.metrics.gauge("queue.backlog", default=-1.0) == -1.0
+    assert exported() == sum(srv.queue.backlog()) == 6
+    srv.submit(2, x, 1.0)  # tenant 2 reaches the size watermark: flush
+    assert srv.queue.flushes == 1
+    assert exported() == sum(srv.queue.backlog()) == 0
+    for t in (0, 0, 1):
+        srv.submit(t, x, 1.0)
+    assert exported() == sum(srv.queue.backlog()) == 3
+    srv.evict(0)
+    assert exported() == sum(srv.queue.backlog()) == 1
 
 
 def test_bf16_read_error_probe_is_small_on_trained_state():
