@@ -229,9 +229,7 @@ class FaultInjector:
                 self._corrupt_log(fault.tenant)
                 self._poison_state(slot, float("nan"))
             elif fault.kind == "drop_flush":
-                queue = self.server.queue
-                record["dropped"] = len(queue._pending[slot])
-                queue._pending[slot].clear()
+                record["dropped"] = self.server.queue.drop_pending(slot)
         _trace.instant("fault.injected", **record)
         self.applied.append(record)
 

@@ -669,11 +669,10 @@ class Server:
 
         Stable schema (validated by scripts/check_bench_schema.py for the
         records the Zipf bench embeds); see README "Observability".
-        The ``queue.backlog`` gauge is set here, at export: summing the
-        backlog walks every slot, too dear for each arrival.
+        The ``queue.backlog`` gauge is set here, at export.
         """
         self.metrics.set_gauge(
-            "queue.backlog", float(sum(self._inner.queue.backlog()))
+            "queue.backlog", float(self._inner.queue.pending_total)
         )
         return {
             "metrics": self.metrics.snapshot(),

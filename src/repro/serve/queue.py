@@ -120,6 +120,15 @@ class MicroBatchQueue:
         # must not round-trip observations through f32).
         self._dtype = np.dtype(lead.dtype)
         self._pending = [deque() for _ in range(self.num_tenants)]
+        # Running backlog counts, so the snapshot tier's per-arrival
+        # watermark test walks no slot: ``_depth_slots[k]`` slots hold
+        # exactly k pending observations (k >= 1; index 0 unused),
+        # ``_total`` over all slots, and ``_max`` bounds the deepest backlog
+        # from above (:meth:`max_backlog` lowers it lazily). Every method
+        # that changes ``_pending`` keeps them exact.
+        self._depth_slots = [0]
+        self._total = 0
+        self._max = 0
         # Watchdog ledger: when each slot's *oldest* pending arrival was
         # enqueued (None = empty backlog). Set on the 0 -> 1 transition,
         # kept across partial flushes (the residual head is older than any
@@ -160,15 +169,60 @@ class MicroBatchQueue:
     def submit(self, tenant: int, x, y) -> None:
         """Enqueue one ``(x, y)`` observation for ``tenant``."""
         self.arrivals[tenant] += 1
-        if not self._pending[tenant] and self.stale_after is not None:
+        q = self._pending[tenant]
+        depth = len(q)
+        if not depth and self.stale_after is not None:
             self._first_pending_at[tenant] = self._clock()
-        self._pending[tenant].append(
-            (np.asarray(x, self._dtype), self._dtype.type(y)),
-        )
+        q.append((np.asarray(x, self._dtype), self._dtype.type(y)))
+        self._count(depth, depth + 1)
+
+    def _count(self, old: int, new: int) -> None:
+        """Move one slot from backlog depth ``old`` to ``new`` in the
+        running counts."""
+        slots = self._depth_slots
+        if old:
+            slots[old] -= 1
+        if new:
+            if new >= len(slots):
+                slots.extend([0] * (new + 1 - len(slots)))
+            slots[new] += 1
+            if new > self._max:
+                self._max = new
+        self._total += new - old
+
+    @property
+    def pending_total(self) -> int:
+        """Pending observations over all slots (O(1))."""
+        return self._total
+
+    def max_backlog(self) -> int:
+        """The deepest per-slot backlog (0 when nothing is pending).
+
+        Amortized O(1): the bound steps down one empty bucket at a time,
+        and each step was paid for by the submit that raised it.
+        """
+        slots, top = self._depth_slots, self._max
+        while top and not slots[top]:
+            top -= 1
+        self._max = top
+        return top
 
     def backlog(self) -> list[int]:
         """Pending observation count per tenant."""
         return [len(q) for q in self._pending]
+
+    def restore_pending(self, pending) -> None:
+        """Install every slot's pending ``(x, y)`` list, in arrival order
+        (checkpoint restore), and recount. Watchdog stamps restart empty."""
+        self._pending = [
+            deque((np.asarray(x, self._dtype), self._dtype.type(y))
+                  for x, y in pend)
+            for pend in pending
+        ]
+        self._first_pending_at = [None] * self.num_tenants
+        self._depth_slots, self._total, self._max = [0], 0, 0
+        for q in self._pending:
+            self._count(0, len(q))
 
     def drop_pending(self, tenant: int) -> int:
         """Discard ``tenant``'s queued observations (eviction hook).
@@ -180,6 +234,7 @@ class MicroBatchQueue:
         dropped = len(self._pending[tenant])
         self._pending[tenant].clear()
         self._first_pending_at[tenant] = None
+        self._count(dropped, 0)
         return dropped
 
     def move_slot(self, src: int, dst: int) -> None:
@@ -188,6 +243,7 @@ class MicroBatchQueue:
         via ``tenant_row``/``set_tenant_row``). ``src`` is left empty."""
         if src == dst:
             return
+        self._count(len(self._pending[dst]), 0)  # dst's own backlog goes
         self._pending[dst] = self._pending[src]
         self._pending[src] = deque()
         self._first_pending_at[dst] = self._first_pending_at[src]
@@ -230,7 +286,7 @@ class MicroBatchQueue:
         one, and bursty tenants still get the full chunk."""
         if not self.adaptive:
             return self.chunk
-        depth = max(1, max(self.backlog(), default=1))
+        depth = max(1, self.max_backlog())
         return min(self.chunk, 1 << (depth - 1).bit_length())
 
     def has_stale(self) -> bool:
@@ -264,7 +320,7 @@ class MicroBatchQueue:
     def flush(self) -> dict[int, list[tuple[float, float]]]:
         """One chunked launch over up to T queued ticks per tenant."""
         bsz, tlen, d = self.num_tenants, self._flush_chunk(), self.input_dim
-        if not any(self._pending):
+        if not self._total:
             _trace.instant("queue.flush.skip", tenants=bsz)
             return {}
         # The four phase spans cover the whole body, so they sum to the
@@ -289,6 +345,11 @@ class MicroBatchQueue:
                         self._first_pending_at[b] = None
                     # Residual backlog keeps its stamp: the surviving head
                     # is at least as old as the arrival that set it.
+                # Every slot drops by min(depth, T): depth k + T lands on k.
+                slots = [0] + self._depth_slots[tlen + 1:]
+                self._depth_slots = slots
+                self._total = sum(k * n for k, n in enumerate(slots))
+                self._max = max(self._max - tlen, 0)
             with _trace.span(
                 "queue.launch", bytes=xs.nbytes + ys.nbytes + mask.nbytes
             ):
@@ -326,7 +387,7 @@ class MicroBatchQueue:
     def drain(self) -> dict[int, list[tuple[float, float]]]:
         """Flush until all backlogs are empty; merge per-tenant results."""
         merged: dict[int, list[tuple[float, float]]] = {}
-        while any(self._pending):
+        while self._total:
             for b, res in self.flush().items():
                 merged.setdefault(b, []).extend(res)
         return merged

@@ -729,16 +729,11 @@ def _install(payload: dict, server) -> None:
     queue.ticks_served = int(q["ticks_served"])
     queue.flushes = int(q["flushes"])
     queue.arrivals = [int(a) for a in q["arrivals"]]
-    queue._pending = [
-        deque((np.asarray(x, queue._dtype), queue._dtype.type(y))
-              for x, y in pend)
-        for pend in q["pending"]
-    ]
-    queue._first_pending_at = [None] * queue.num_tenants
+    queue.restore_pending(q["pending"])
     now = inner._clock()
     inner._arrival_times = [
         deque((i, now) for i in range(len(pend)))
-        for pend in queue._pending
+        for pend in q["pending"]
     ]
     inner._snapshot = StateSnapshot(
         state=state,

@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.bank import bank_predict_block, evict_tenant, rebuild_tenant
 from repro.features.base import FeatureLike
+from repro.obs import telemetry as _telemetry
 from repro.obs import trace as _trace
 from repro.serve.queue import MicroBatchQueue
 
@@ -331,25 +332,40 @@ class SnapshotServer:
         flushes; ``submit`` calls it after every arrival.
 
         The ``snapshot.watermark`` span covers the test alone, so a flush
-        it triggers is a sibling span, not a child."""
+        it triggers is a sibling span, not a child. Each such flush counts
+        once in ``snapshot.watermark_flushes{reason=size|age}``."""
         with _trace.span("snapshot.watermark"):
-            due = self._watermark_due()
-        return self.flush() if due else {}
+            reason = self._watermark_due()
+        if reason is None:
+            return {}
+        _telemetry.registry().counter(
+            "snapshot.watermark_flushes", reason=reason
+        ).inc()
+        return self.flush()
 
-    def _watermark_due(self) -> bool:
-        backlog = self.queue.backlog()
-        if not any(backlog):
-            return False
-        if self.size_watermark is not None and max(backlog) >= self.size_watermark:
-            return True
+    def _watermark_due(self) -> Optional[str]:
+        """Which watermark trips now: ``"size"``, ``"age"`` or None.
+
+        The size test reads the queue's running counts, so it costs O(1)
+        per arrival; the age test still walks the slots' arrival times,
+        and runs only when nothing is due by size."""
+        queue = self.queue
+        if not queue.pending_total:
+            return None
+        if (
+            self.size_watermark is not None
+            and queue.max_backlog() >= self.size_watermark
+        ):
+            return "size"
         if self.age_watermark is not None:
             oldest = min(
                 (t[0][1] for t in self._arrival_times if t), default=None
             )
-            return oldest is not None and (
+            if oldest is not None and (
                 self._clock() - oldest >= self.age_watermark
-            )
-        return False
+            ):
+                return "age"
+        return None
 
     def flush(self) -> dict:
         """One chunked train launch on the live state; publish when due.
@@ -368,7 +384,7 @@ class SnapshotServer:
     def drain(self) -> dict:
         """Flush until every backlog is empty; merge per-tenant results."""
         merged: dict = {}
-        while any(self.queue.backlog()):
+        while self.queue.pending_total:
             for tenant, served in self.flush().items():
                 merged.setdefault(tenant, []).extend(served)
         return merged
@@ -507,7 +523,7 @@ class SnapshotServer:
         counters, replay logs with their truncation flags, the evicted
         set) is wiped with them. Pending observations must be drained
         first."""
-        if any(self.queue.backlog()):
+        if self.queue.pending_total:
             raise RuntimeError("reset with pending observations; drain first")
         self.queue.state = state
         self.queue.ticks_served = 0

@@ -90,7 +90,9 @@ def _expected_outcome(kind, learner):
 @pytest.mark.parametrize(
     "kind", ["nan_state", "asym_pmat", "log_corrupt", "drop_flush"]
 )
-def test_fault_matrix_detect_quarantine_repair(kind, learner):
+def test_fault_matrix_detect_quarantine_repair(
+    kind, learner, assert_watermark_state
+):
     srv = _make(learner, recovery=True)
     ctrl = _make(learner, probe=True)
     traffic = _traffic(3, 60)
@@ -106,6 +108,17 @@ def test_fault_matrix_detect_quarantine_repair(kind, learner):
         s.drain()
     assert srv.probe.total_events == 0
 
+    if kind == "drop_flush":
+        # Between the fault and the flush it precedes, the dropped backlog
+        # has left the queue's running counts and the watermark decision.
+        inner = srv.snapshot_server
+        inner_flush = inner.flush
+
+        def checked_flush():
+            assert_watermark_state(srv)
+            return inner_flush()
+
+        inner.flush = checked_flush
     inj = FaultInjector(
         srv, FaultPlan([Fault(kind, tenant=_TENANT, at_flush=0)])
     ).attach()
@@ -118,6 +131,9 @@ def test_fault_matrix_detect_quarantine_repair(kind, learner):
     ctrl.drain()
     inj.detach()
     assert inj.applied and inj.applied[0]["flush"] == 0
+    if kind == "drop_flush":
+        assert inj.applied[0]["dropped"] > 0
+        assert_watermark_state(srv)
 
     # Detection, quarantine and the full repair all happened inside the
     # faulted flush's fold.

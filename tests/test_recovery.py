@@ -100,7 +100,9 @@ def test_wal_tolerates_torn_tail_and_resumes_seq(tmp_path):
 
 
 @pytest.mark.parametrize("learner", ["klms", "krls", "qklms"])
-def test_checkpoint_restore_roundtrip_bitwise(tmp_path, learner):
+def test_checkpoint_restore_roundtrip_bitwise(
+    tmp_path, learner, assert_watermark_state
+):
     kw = {
         "klms": dict(mu=0.3),
         "krls": dict(lam=0.1, beta=0.99),
@@ -124,6 +126,9 @@ def test_checkpoint_restore_roundtrip_bitwise(tmp_path, learner):
     assert _leaves_equal(a.snapshot.state, b.snapshot.state)
     assert a.snapshot.version == b.snapshot.version
     assert a.queue.backlog() == b.queue.backlog()
+    assert max(b.queue.backlog()) > 0
+    assert_watermark_state(a)
+    assert_watermark_state(b)
     assert a.queue.ticks_served == b.queue.ticks_served
     assert a.queue.flushes == b.queue.flushes
     assert a.policy.state_dict() == b.policy.state_dict()
@@ -365,7 +370,7 @@ def test_ticks_lag_ledger_tracks_lost_arrivals():
     assert srv._slot_lags() == [0, 0, 0]
     # Silently lose a backlog (bypassing the facade's accounting).
     srv.submit(1, np.zeros(3, np.float32), 1.0)
-    srv.queue._pending[1].clear()
+    srv.queue.drop_pending(1)
     srv.submit(0, np.zeros(3, np.float32), 0.0)  # drive a real flush
     srv.flush()
     assert srv._slot_lags()[1] == 1

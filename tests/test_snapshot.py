@@ -7,9 +7,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.bank import resize_bank
 from repro.core.rff import sample_rff
 from repro.features.base import featurize
-from repro.serve import klms_snapshot_server, krls_snapshot_server
+from repro.obs import telemetry
+from repro.obs.faults import Fault, FaultInjector, FaultPlan
+from repro.serve import api as serve_api
+from repro.serve import klms_snapshot_server, krls_snapshot_server, make_server
+from repro.serve.snapshot import SnapshotServer
 
 RFF = sample_rff(jax.random.PRNGKey(0), 4, 48, sigma=3.0)
 _RNG = np.random.RandomState(7)
@@ -223,6 +228,174 @@ def test_reset_requires_drained_queue():
     srv.drain()
     srv.reset(srv.queue.state)
     assert srv.snapshot.version == 0 and srv.staleness == 0
+
+
+# ---------------------------------------------------------------------------
+# The watermark test reads the queue's running counts: same decisions as a
+# scan of every slot's backlog, at O(1) per arrival.
+# ---------------------------------------------------------------------------
+
+
+def _scan_watermark(srv):
+    """The watermark decision recomputed from a scan of every backlog."""
+    backlog = srv.queue.backlog()
+    if not any(backlog):
+        return None
+    if srv.size_watermark is not None and max(backlog) >= srv.size_watermark:
+        return "size"
+    if srv.age_watermark is not None:
+        oldest = min(
+            (t[0][1] for t in srv._arrival_times if t), default=None
+        )
+        if oldest is not None and srv._clock() - oldest >= srv.age_watermark:
+            return "age"
+    return None
+
+
+class _ScanWatermarkServer(SnapshotServer):
+    """Reference server: decides every watermark by the scan."""
+
+    def _watermark_due(self):
+        return _scan_watermark(self)
+
+
+def _assert_counts(queue):
+    backlog = queue.backlog()
+    assert queue.pending_total == sum(backlog)
+    assert queue.max_backlog() == max(backlog, default=0)
+
+
+def assert_trees_bitwise(a, b):
+    for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+
+
+_HP = {"klms": dict(mu=0.5), "krls": dict(lam=1e-2, beta=0.999)}
+_OPS = ("submit", "queue.submit", "flush", "queue.flush", "maybe_flush",
+        "drop_pending", "evict", "readmit", "move_slot", "adopt", "reset")
+_OP_P = np.array([12, 3, 1, 1, 2, 1, 1, 1, 1, 0.5, 0.3])
+
+
+def _apply_op(srv, op, rng):
+    """One random operation on a facade ``srv`` (no policy: tenant = slot)."""
+    inner, slots = srv.snapshot_server, srv.slots
+    hot = rng.choice(slots, p=np.arange(slots, 0, -1) / (slots * (slots + 1) / 2))
+    i = rng.integers(len(XS))
+    if op == "submit":
+        srv.submit(int(hot), XS[i], YS[i])
+    elif op == "queue.submit":
+        srv.queue.submit(int(hot), XS[i], YS[i])
+    elif op == "flush":
+        srv.flush()
+    elif op == "queue.flush":
+        srv.queue.flush()
+    elif op == "maybe_flush":
+        srv.maybe_flush()
+    elif op == "drop_pending":
+        srv.queue.drop_pending(int(hot))
+    elif op == "evict":
+        if int(hot) not in srv.evicted:
+            srv.evict(int(hot))
+    elif op == "readmit":
+        if srv.evicted:
+            srv.readmit(min(srv.evicted))
+    elif op == "move_slot":
+        src, dst = rng.choice(slots, size=2, replace=False)
+        inner.move_slot(int(src), int(dst))
+    elif op == "adopt":
+        new = 8 if slots == 4 else 4
+        for s in range(new, slots):
+            srv.queue.drop_pending(s)
+        inner.adopt_resized(
+            resize_bank(srv.queue.state, new, fresh_row=srv._fresh_row)
+        )
+    else:
+        srv.drain()
+        srv.reset()
+
+
+@pytest.mark.parametrize("age_watermark", [None, 0.1])
+@pytest.mark.parametrize("size_watermark", [3, 6])  # below / above chunk 4
+@pytest.mark.parametrize("learner", ["klms", "krls"])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_running_counts_match_backlog_scan(
+    seed, learner, size_watermark, age_watermark
+):
+    """Random mixes of every operation that changes a backlog: after each
+    step the running counts equal a scan of ``backlog()``, the watermark
+    decision equals the scan's, and a reference server that decides by the
+    scan flushes at the same steps and ends bitwise equal."""
+    rng = np.random.default_rng(seed)
+    clock = FakeClock()
+    kw = dict(
+        feature_map=RFF, bank=4, chunk=4, mode="xla", publish_every=2,
+        size_watermark=size_watermark, age_watermark=age_watermark,
+        clock=clock, **_HP[learner],
+    )
+    srv = make_server(learner, **kw)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(serve_api, "SnapshotServer", _ScanWatermarkServer)
+        ref = make_server(learner, **kw)
+    assert type(ref.snapshot_server) is _ScanWatermarkServer
+    # Both take the same drop_flush faults at the same flush indices; the
+    # counts are checked between a fault and the flush it precedes too.
+    plan = FaultPlan([
+        Fault("drop_flush", tenant=int(rng.integers(4)), at_flush=int(f))
+        for f in rng.choice(40, size=6, replace=False)
+    ])
+    inner_flush = srv.snapshot_server.flush
+
+    def checked_flush():
+        _assert_counts(srv.queue)
+        return inner_flush()
+
+    srv.snapshot_server.flush = checked_flush
+    injectors = [FaultInjector(s, plan).attach() for s in (srv, ref)]
+    for _ in range(200):
+        clock.t += 0.04 * (rng.random() < 0.15)
+        op = _OPS[rng.choice(len(_OPS), p=_OP_P / _OP_P.sum())]
+        step = rng.bit_generator.state
+        for s in (srv, ref):
+            rng.bit_generator.state = step
+            _apply_op(s, op, rng)
+        _assert_counts(srv.queue)
+        assert srv.snapshot_server._watermark_due() == _scan_watermark(
+            srv.snapshot_server
+        ), op
+        assert srv.queue.backlog() == ref.queue.backlog(), op
+        assert srv.queue.flushes == ref.queue.flushes, op
+        assert srv.snapshot.version == ref.snapshot.version, op
+    assert injectors[0].applied == injectors[1].applied
+    for s in (srv, ref):
+        s.drain()
+    assert_trees_bitwise(srv.queue.state, ref.queue.state)
+    assert_trees_bitwise(srv.snapshot.state, ref.snapshot.state)
+
+
+def test_submit_path_walks_no_slot(monkeypatch):
+    """At 4096 slots the per-arrival watermark test never builds the
+    backlog list; every flush it fires counts once under its reason."""
+    srv = make_server(
+        "klms", feature_map=RFF, bank=4096, chunk=4, mode="xla", mu=0.5,
+        size_watermark=4,
+    )
+
+    def walked():
+        raise AssertionError("backlog() walked every slot on the submit path")
+
+    monkeypatch.setattr(srv.queue, "backlog", walked)
+    counted = telemetry.registry().count(
+        "snapshot.watermark_flushes", reason="size"
+    )
+    for n in range(300):
+        srv.submit(n % 8, XS[n % len(XS)], YS[n % len(YS)])
+    # Round robin over 8 tenants: some tenant reaches depth 4 at arrival
+    # 24, and after each flush the next one does 25 arrivals later.
+    fired = srv.queue.flushes
+    assert fired == len(range(24, 300, 25))
+    assert telemetry.registry().count(
+        "snapshot.watermark_flushes", reason="size"
+    ) - counted == fired
 
 
 # ---------------------------------------------------------------------------
