@@ -7,6 +7,14 @@ due, when the call went out, when a write was published (the return of the
 call whose flush published it) and what each read returned. A flush's
 prior predictions are taken where the queue produces them (its ``flush``
 return value, which ``submit`` does not pass on).
+
+A server with a slot policy (``make_server(policy=...)``) runs more tenants
+than slots; the path is chosen once per run. Its flushes are keyed by slot,
+mapped to tenants through the slot's occupant, which the client learns at
+each admission (``policy.lookup`` before and after a write). An admission
+rebuilds the tenant from its log and publishes, so the client's pending
+writes of that tenant are then published, with no prior. A read of a tenant
+that is not resident is served cold (from a fresh state).
 """
 from __future__ import annotations
 
@@ -18,7 +26,7 @@ import os
 import shutil
 import tempfile
 import time
-from collections import deque
+from collections import defaultdict, deque
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -40,7 +48,12 @@ class NoChip(RuntimeError):
 
 
 class CompileInWindow(RuntimeError):
-    """A program was traced or compiled inside the measured window."""
+    """A program was traced or compiled inside the measured window. The
+    run's ``result`` and ``details`` ride along; the run is not valid."""
+
+    def __init__(self, counts: dict, result: dict, details: dict):
+        super().__init__(json.dumps(counts))
+        self.result, self.details = result, details
 
 
 class CompileCounter:
@@ -95,18 +108,28 @@ class GcWatch:
 
 
 class Ledger:
-    """The client's record of every request of one run."""
+    """The client's record of every request of one run.
 
-    def __init__(self, sched: traffic.Schedule, tenants: int):
+    ``owner`` (slot -> tenant) is None where a tenant is its slot; with a
+    slot policy it is kept per admission (:meth:`install`)."""
+
+    def __init__(self, sched: traffic.Schedule, tenants: int,
+                 owner: Optional[dict] = None):
         self.issued: list = []
         self.done: list = []  # read: value on the host; write: published
         self.value: list = []  # read: its value; write: the prior
         self.pub: list = []  # read: its tenant's published writes when served
         self.grow(len(sched))
-        self.pending = [deque() for _ in range(tenants)]
+        self.owner = owner
+        # With a policy most tenants may never write: their queues are made
+        # on first use.
+        self.pending = ([deque() for _ in range(tenants)] if owner is None
+                        else defaultdict(deque))
         self.published = [0] * tenants
         self.flushed: list = []  # queue.flush results not yet settled
-        self.flushes: list = []  # (t0, t1, active tenants, ticks, published at)
+        self.flushes: list = []  # (t0, t1, active slots, ticks, published at)
+        self.rebuilds: list = []  # (published at, writes) of admissions
+        self.rebuilt: list = []  # writes an admission's rebuild published
         self.raised = 0
         self.errors: list = []
 
@@ -120,9 +143,11 @@ class Ledger:
 
     def settle(self, now: float) -> None:
         """Mark the writes of the flushes since the last call published."""
+        owner = self.owner
         for t0, t1, res in self.flushed:
             ticks = 0
-            for tenant, outs in res.items():
+            for slot, outs in res.items():
+                tenant = slot if owner is None else owner[slot]
                 pend = self.pending[tenant]
                 for pred, _err in outs:
                     i = pend.popleft()
@@ -132,6 +157,22 @@ class Ledger:
                 ticks += len(outs)
             self.flushes.append((t0, t1, len(res), ticks, now))
         self.flushed.clear()
+
+    def install(self, tenant: int, slot: int, now: float, keep: int = 0) -> None:
+        """``tenant`` now occupies ``slot``: an admission rebuilt it from its
+        log and published, which trains every pending write of it but the
+        last ``keep`` (the write being submitted, queued after the rebuild).
+        Such a write gets no prior."""
+        self.owner[slot] = tenant
+        pend = self.pending[tenant]
+        n = len(pend) - keep
+        for _ in range(n):
+            i = pend.popleft()
+            self.done[i] = now
+            self.rebuilt.append(i)
+        if n > 0:
+            self.published[tenant] += n
+            self.rebuilds.append((now, n))
 
     def fail(self, i: int, exc: BaseException) -> None:
         self.raised += 1
@@ -152,6 +193,14 @@ def capture_flushes(server, led: Ledger) -> None:
         return res
 
     queue.flush = recorded_flush
+
+
+def lifecycle(server) -> dict:
+    """The slot policy's counts of evictions and of readmissions (rebuilds
+    from the log); empty without a policy."""
+    if server.policy is None:
+        return {}
+    return {k: server.metrics.count(k) for k in ("evictions", "readmissions")}
 
 
 def wait_until(t: float) -> None:
@@ -210,6 +259,87 @@ def drive(server, led: Ledger, sched: traffic.Schedule, lo: int,
     return i
 
 
+def drive_policy(server, led: Ledger, sched: traffic.Schedule, lo: int,
+                 hi: Optional[int], deadline: float,
+                 t_open: Optional[float]) -> int:
+    """:func:`drive` for a server with a slot policy. A write whose tenant
+    goes from not resident to resident settles what the admission's rebuild
+    published; a read of a tenant that is not resident has seen no write."""
+    submit, predict, maybe_flush = server.submit, server.predict, server.maybe_flush
+    lookup = server.policy.lookup
+    key, y, x, is_read = sched.key_l, sched.y_l, sched.x, sched.read_l
+    due = sched.due_l if t_open is not None else None
+    issued, done, value, pub = led.issued, led.done, led.value, led.pub
+    pending, published, flushed = led.pending, led.published, led.flushed
+    i = lo
+    while hi is None or i < hi:
+        now = clock()
+        if now >= deadline:
+            break
+        if i == len(key):
+            if not sched.extend():
+                break
+            led.grow(len(key))
+        if due is not None:
+            t_due = t_open + due[i]
+            if now < t_due:
+                maybe_flush()
+                if flushed:
+                    led.settle(clock())
+                wait_until(t_due)
+                now = clock()
+        k = key[i]
+        issued[i] = now
+        try:
+            if is_read[i]:
+                pub[i] = published[k] if lookup(k) is not None else 0
+                value[i] = float(predict(k, x[i]))
+                done[i] = clock()
+            else:
+                was = lookup(k)
+                pending[k].append(i)
+                submit(k, x[i], y[i])
+                if was is None:
+                    slot = lookup(k)
+                    if slot is not None:
+                        led.install(k, slot, clock(), keep=1)
+        except Exception as e:  # counted as failed; the run goes on
+            if not is_read[i] and pending[k] and pending[k][-1] == i:
+                pending[k].pop()
+            led.fail(i, e)
+        if flushed:
+            led.settle(clock())
+        i += 1
+    return i
+
+
+def checked_rows(server, led: Ledger, ids: np.ndarray) -> dict:
+    """With a slot policy: each checked tenant's row, pulled from its slot
+    one tenant at a time. A tenant that is not resident is first readmitted,
+    which rebuilds it from its log and so publishes its pending writes."""
+    lookup = server.policy.lookup
+    rows = []
+    for t in ids.tolist():
+        if lookup(t) is None:
+            server.readmit(t)
+            led.install(t, lookup(t), clock())
+        slot = lookup(t)
+        rows.append({f: np.asarray(a[slot])
+                     for f, a in server.queue.state._asdict().items()})
+    return {f: np.stack([r[f] for r in rows]) for f in rows[0]}
+
+
+def untrained(got: dict, ref: dict, published: list) -> int:
+    """Acknowledged writes the step counters disagree with. Each counter
+    (of the tenants ``got["stepped"]``) is held against the writes the
+    configuration trains: the reference's ``step`` of the checked tenants
+    where it gives one, else the client's count of published writes."""
+    want = np.array(published)
+    if "step" in ref:
+        want[got["ids"]] = ref["step"]
+    return int(np.abs(got["step"] - want[got["stepped"]]).sum())
+
+
 @dataclass
 class RunView:
     """What the reference sees of a run: the bench-made feature map and the
@@ -250,6 +380,25 @@ def make_feature_map(cfg: dict, seed: int):
     return draw(jax.random.PRNGKey(device_seed(seed)))
 
 
+def build_server(cfg: dict, mix: dict, w, b, tracer=None):
+    """The system under test: ``make_server`` as the configuration and the
+    mix state it (``hp`` may name a slot policy and its log)."""
+    from repro.core.rff import RFF
+    from repro.serve import make_server
+
+    return make_server(
+        cfg["learner"],
+        feature_map=RFF(omega=w, bias=b),
+        bank=cfg["slots"],
+        chunk=cfg["chunk"],
+        publish_every=cfg["publish_every"],
+        size_watermark=mix["size_watermark"],
+        age_watermark=mix["age_watermark"],
+        trace=tracer,
+        **cfg["hp"],
+    )
+
+
 def percentile(values, q: float) -> Optional[float]:
     """Percentile of latencies; a request with no answer (NaN) counts as
     later than any other."""
@@ -257,9 +406,10 @@ def percentile(values, q: float) -> Optional[float]:
     return float(np.percentile(v, q)) if len(v) else None
 
 
-def numbers(view: RunView, got: dict, ref: dict) -> dict:
+def numbers(view: RunView, got: dict, ref: dict, flushed: np.ndarray) -> dict:
     """The compared numbers: per tenant, the norm-relative gap of the final
-    state, of its writes' prior predictions and of its reads."""
+    state, of its writes' prior predictions (those a flush returned:
+    ``flushed``, over the checked tenants' writes) and of its reads."""
     ids = got["ids"]
     out = {}
     for leaf in ("theta", "pmat"):
@@ -270,7 +420,8 @@ def numbers(view: RunView, got: dict, ref: dict) -> dict:
             )
     wk = view.write_key[np.isin(view.write_key, ids)]
     rk = view.read_key[np.isin(view.read_key, ids)]
-    out["prior"] = check.worst_group(got["prior"], ref["prior"], wk)
+    out["prior"] = check.worst_group(
+        got["prior"][flushed], ref["prior"][flushed], wk[flushed])
     out["read"] = check.worst_group(got["read"], ref["read"], rk)
     out["untrained"] = got["untrained"]
     return out
@@ -297,8 +448,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
             f"{len(devs)} {devs[0].platform} device(s)"
         )
     from repro.obs.trace import Tracer
-    from repro.serve import make_server
-    from repro.core.rff import RFF
 
     counter = CompileCounter()
     cfg, mix = cell.cfg, cell.mix
@@ -309,21 +458,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
     parts["streams"] = clock() - t
     t = clock()
     tracer = Tracer(capacity=TRACE_SPANS, jax_annotations=True) if trace else None
-    server = make_server(
-        cfg["learner"],
-        feature_map=RFF(omega=w, bias=b),
-        bank=cfg["slots"],
-        chunk=cfg["chunk"],
-        publish_every=cfg["publish_every"],
-        size_watermark=mix["size_watermark"],
-        age_watermark=mix["age_watermark"],
-        trace=tracer,
-        **cfg["hp"],
-    )
+    server = build_server(cfg, mix, w, b, tracer)
     jax.block_until_ready(server.queue.state)
     parts["server"] = clock() - t
     t = clock()
-    led = Ledger(sched, cfg["tenants"])
+    pol = server.policy
+    owner = None if pol is None else {s: k for k, s in pol.resident.items()}
+    led = Ledger(sched, cfg["tenants"], owner)
+    run = drive if pol is None else drive_policy
     capture_flushes(server, led)
     if inject is not None:
         inject(server)
@@ -331,7 +473,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
     # Set-up: the mix's warm-up requests, closed loop, then a drain. They
     # compile (or load) every program the window runs, and the reference
     # replays them like any other request.
-    n_warm = drive(server, led, sched, 0, sched.warmup, math.inf, None)
+    n_warm = run(server, led, sched, 0, sched.warmup, math.inf, None)
     server.drain()
     led.settle(clock())
     jax.block_until_ready(server.queue.state)
@@ -346,6 +488,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
     hi = len(sched) if sched.open_loop else None
     prof_s = PROFILE_SHARE * seconds if trace else 0.0
     mark0 = counter.mark()
+    lifecycle0 = lifecycle(server)
     gcw.active = True
     t_open = clock()
     t_close = t_open + seconds
@@ -355,23 +498,32 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
         split = lo + int(np.searchsorted(sched.due[lo:], seconds - prof_s))
     else:
         split = None
-    i = drive(server, led, sched, lo, split, t_close - prof_s if not open_loop
-              else stop, t_open if open_loop else None)
+    i = run(server, led, sched, lo, split, t_close - prof_s if not open_loop
+            else stop, t_open if open_loop else None)
     prof = None
     if trace:
         prof = _Profile(tracer)
-        i = drive(server, led, sched, i, hi, stop, t_open if open_loop else None)
+        i = run(server, led, sched, i, hi, stop, t_open if open_loop else None)
         prof.stop()
     t_end = clock()
     mark1 = counter.mark()
+    lifecycle1 = lifecycle(server)
     counter.close()
     gc_window = gcw.close()
 
     # After the window: publish what is pending (an open loop waits on the
-    # age watermark, as a client would), then drain.
+    # age watermark, as a client would), then drain. A flush publishes no
+    # write of a tenant that is not resident.
+    if pol is None:
+        def waiting():
+            return any(led.pending)
+    else:
+        def waiting():
+            return any(p and pol.lookup(k) is not None
+                       for k, p in led.pending.items())
     if open_loop:
         limit = clock() + 60.0
-        while any(led.pending) and clock() < limit:
+        while waiting() and clock() < limit:
             server.maybe_flush()
             led.settle(clock())
             time.sleep(1e-3)
@@ -394,26 +546,46 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
         read_x=s_x[r_idx], read_pub=np.asarray(led.pub)[r_idx],
     )
     ids = np.asarray(cell.reference.tenants(view))
-    state = server.queue.state
-    value = np.asarray(led.value)
-    got = {
-        "ids": ids,
-        "theta": np.asarray(state.theta)[ids],
-        "prior": value[w_idx][np.isin(view.write_key, ids)],
-        "read": value[r_idx][np.isin(view.read_key, ids)],
-        "untrained": int(np.abs(
-            np.asarray(state.step) - np.asarray(led.published)
-        ).sum()),
-    }
-    if hasattr(state, "pmat"):
-        got["pmat"] = np.asarray(state.pmat[ids])
-    unpublished = sum(len(p) for p in led.pending)
     snapshot_behind = server.snapshot.tick != server.queue.ticks_served
-    del state, server
+    policy_details = {}
+    if pol is None:
+        unpublished = sum(len(p) for p in led.pending)
+        state = server.queue.state
+        got = {"theta": np.asarray(state.theta)[ids],
+               "step": np.asarray(state.step)}
+        if hasattr(state, "pmat"):
+            got["pmat"] = np.asarray(state.pmat[ids])
+        got["stepped"] = np.arange(len(got["step"]))
+        del state
+    else:
+        # A write still pending whose tenant is not resident was dropped at
+        # its eviction or rejected: it is in the tenant's log, not lost.
+        unpublished = not_resident = 0
+        for k, p in led.pending.items():
+            if pol.lookup(k) is None:
+                not_resident += len(p)
+            else:
+                unpublished += len(p)
+        policy_details = {
+            "logged_not_resident": not_resident,
+            "rebuilt_writes": len(led.rebuilt),
+            "checked_not_resident": sum(pol.lookup(t) is None for t in ids.tolist()),
+            **{f"{k}_in_window": lifecycle1[k] - lifecycle0[k] for k in lifecycle0},
+        }
+        got = {**checked_rows(server, led, ids), "stepped": ids}
+    value = np.asarray(led.value)
+    checked_w = np.isin(view.write_key, ids)
+    got.update(ids=ids, prior=value[w_idx][checked_w],
+               read=value[r_idx][np.isin(view.read_key, ids)])
+    no_prior = np.zeros(len(is_read), bool)
+    no_prior[led.rebuilt] = True
+    flushed = ~no_prior[w_idx][checked_w]
+    del server, pol  # the policy's cost function holds the server
     gc.collect()  # the bank (GBs of P for KRLS) goes before a next seed's
     t_ref = clock()
     ref = cell.reference.replay(view, ids, "f64")
-    nums = numbers(view, got, ref)
+    got["untrained"] = untrained(got, ref, led.published)
+    nums = numbers(view, got, ref, flushed)
     ok, checks = check.judge(nums, cfg["limits"])
     ref_s = clock() - t_ref
     details = {
@@ -428,13 +600,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
         "errors": led.errors, "unpublished": unpublished,
         "snapshot_behind": snapshot_behind, "memory_peak_bytes": peak,
         "checked_tenants": len(ids), "gc_in_window": gc_window,
+        **policy_details,
     }
     if control:
         ctl = cell.reference.replay(view, ids, "bf16")
-        ctl_nums = numbers(view, {**ctl, "ids": ids, "untrained": 0}, ref)
+        ctl_nums = numbers(view, {**ctl, "ids": ids, "untrained": 0}, ref,
+                           flushed)
         details["control"] = check.judge(ctl_nums, cfg["limits"])[1]
-    if any(x for x in details["compiles_in_window"].values()):
-        raise CompileInWindow(json.dumps(details["compiles_in_window"]))
 
     # End-to-end metrics, from the client's ledger.
     issued = np.asarray(led.issued)
@@ -465,7 +637,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
     # the window (first to last publish in it): a publish lands a whole
     # flush at once, so counting up to the window's edge would step the
     # rate by a flush's worth of arrivals.
-    pubs = [(t, n) for _, _, _, n, t in led.flushes if t_open < t <= t_close]
+    pubs = sorted(
+        [(t, n) for _, _, _, n, t in led.flushes if t_open < t <= t_close]
+        + [(t, n) for t, n in led.rebuilds if t_open < t <= t_close],
+        key=lambda p: p[0])
     if len(pubs) >= 2 and pubs[-1][0] > pubs[0][0]:
         details["ingest_rate"] = (
             sum(n for _, n in pubs[1:]) / (pubs[-1][0] - pubs[0][0]))
@@ -507,6 +682,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
             shutil.copy(prof.xplane, save_trace)
         prof.cleanup()
     result["checks"] = checks
+    if any(x for x in details["compiles_in_window"].values()):
+        raise CompileInWindow(details["compiles_in_window"], result, details)
     return result, details
 
 
