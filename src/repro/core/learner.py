@@ -54,6 +54,7 @@ from repro.core.krls_ald import ald_krls_init, ald_krls_predict, ald_krls_step
 from repro.core.qklms import qklms_init, qklms_predict, qklms_step
 from repro.core.scan import (
     ScanElement,
+    gated_run,
     klms_scan_element,
     krls_scan_element,
     nklms_scan_element,
@@ -85,9 +86,9 @@ class OnlineLearner:
         (:class:`repro.core.scan.ScanElement`), or None for learners whose
         state update is not an associative element (growing-dictionary
         baselines, sharded programs).
-      replay_fn: ``(xs, ys, state=None, mode=..., chunk=...) -> state`` —
-        the parallel-in-time state rebuild (core/scan.py), or None to fall
-        back to a sequential ``run`` in :meth:`rebuild`.
+      replay_fn: ``(xs, ys, state=None, mode=..., chunk=..., mask=...)
+        -> state`` — the parallel-in-time state rebuild (core/scan.py), or
+        None to fall back to a sequential ``run`` in :meth:`rebuild`.
     """
 
     init_fn: Callable
@@ -126,6 +127,7 @@ class OnlineLearner:
         state=None,
         mode: str = "scan",
         chunk: Optional[int] = None,
+        mask: Optional[jax.Array] = None,
     ):
         """Reconstruct the final state from a replay log (no per-tick outs).
 
@@ -133,12 +135,21 @@ class OnlineLearner:
         the ordinary scan — bitwise the training path. ``"scan"`` /
         ``"blocked"`` rebuild through the associative-element engine in
         O(log T) / O(Tc + log nc) depth within the tolerances pinned in
-        tests/test_replay.py.
+        tests/test_replay.py. ``mask`` ``(T,)`` marks a padded log's real
+        ticks (``core.scan.pad_log``); padding replays as the identity and
+        the rebuilt step counts real ticks only.
         """
         if self.replay_fn is None or mode == "sequential":
+            if mask is not None:
+                return gated_run(
+                    self.step_fn, self.init() if state is None else state,
+                    xs, ys, mask,
+                )
             final, _ = self.run(state, xs, ys)
             return final
-        return self.replay_fn(xs, ys, state=state, mode=mode, chunk=chunk)
+        return self.replay_fn(
+            xs, ys, state=state, mode=mode, chunk=chunk, mask=mask
+        )
 
 
 def klms_learner(rff: FeatureLike, mu: float) -> OnlineLearner:
@@ -153,8 +164,10 @@ def klms_learner(rff: FeatureLike, mu: float) -> OnlineLearner:
         step_fn=lambda s, x, y: rff_klms_step(s, (x, y), rff, mu),
         predict_fn=lambda s, x: featurize(rff, x) @ s.theta,
         scan_element=klms_scan_element(mu),
-        replay_fn=lambda xs, ys, state=None, mode="scan", chunk=None: (
-            replay_klms(rff, xs, ys, mu, state=state, mode=mode, chunk=chunk)
+        replay_fn=lambda xs, ys, state=None, mode="scan", chunk=None, mask=None: (
+            replay_klms(
+                rff, xs, ys, mu, state=state, mode=mode, chunk=chunk, mask=mask
+            )
         ),
     )
 
@@ -170,10 +183,10 @@ def nklms_learner(
         step_fn=lambda s, x, y: rff_nklms_step(s, (x, y), rff, mu, eps),
         predict_fn=lambda s, x: featurize(rff, x) @ s.theta,
         scan_element=nklms_scan_element(mu, eps),
-        replay_fn=lambda xs, ys, state=None, mode="scan", chunk=None: (
+        replay_fn=lambda xs, ys, state=None, mode="scan", chunk=None, mask=None: (
             replay_klms(
                 rff, xs, ys, mu, state=state, mode=mode, chunk=chunk,
-                normalized=True, eps=eps,
+                normalized=True, eps=eps, mask=mask,
             )
         ),
     )
@@ -190,10 +203,10 @@ def krls_learner(
         step_fn=lambda s, x, y: rff_krls_step(s, (x, y), rff, beta),
         predict_fn=lambda s, x: featurize(rff, x) @ s.theta,
         scan_element=krls_scan_element(beta),
-        replay_fn=lambda xs, ys, state=None, mode="scan", chunk=None: (
+        replay_fn=lambda xs, ys, state=None, mode="scan", chunk=None, mask=None: (
             replay_krls(
                 rff, xs, ys, lam=lam, beta=beta, state=state, mode=mode,
-                chunk=chunk,
+                chunk=chunk, mask=mask,
             )
         ),
     )

@@ -45,6 +45,13 @@ Execution modes (``replay_klms`` / ``replay_krls``):
 Non-trig feature families (taylor) run the generic ``featurize`` path under
 ``"scan"``; ``"blocked"`` requires the canonical affine-trig form and falls
 back to ``"scan"`` otherwise.
+
+Padded logs: ``"scan"`` and ``"sequential"`` take a tick ``mask``, so a log
+of any length replays at a power-of-two bucket (:func:`pad_log`,
+:func:`replay_bucket`) and a server compiles one replay program per bucket,
+not one per log length. A masked tick is the identity: the element algebra's
+identity under ``"scan"``, a gated tick under ``"sequential"``; the rebuilt
+``step`` counts the real ticks only.
 """
 from __future__ import annotations
 
@@ -53,9 +60,16 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.klms import LMSState, rff_klms_init, rff_klms_run
-from repro.core.krls import RLSState, rff_krls_init, rff_krls_run
+from repro.core.klms import (
+    LMSState,
+    rff_klms_init,
+    rff_klms_run,
+    rff_klms_step,
+    rff_nklms_step,
+)
+from repro.core.krls import RLSState, rff_krls_init, rff_krls_run, rff_krls_step
 from repro.features.base import (
     FeatureLike,
     as_trig_or_none,
@@ -82,6 +96,10 @@ __all__ = [
     "krls_scan_element",
     "replay_klms",
     "replay_krls",
+    "replay_bucket",
+    "replay_buckets",
+    "pad_log",
+    "gated_run",
 ]
 
 
@@ -316,6 +334,60 @@ def _last(tree):
     return jax.tree.map(lambda a: a[-1], tree)
 
 
+def replay_bucket(n: int, capacity: int) -> int:
+    """The padded length a log of ``n`` ticks replays at: the next power of
+    two, at most ``capacity`` (the log's ring size), never below ``n``."""
+    return max(n, min(1 << max(n - 1, 0).bit_length(), capacity))
+
+
+def replay_buckets(capacity: int) -> list[int]:
+    """Every bucket a log ring of ``capacity`` replays at: 1, 2, 4, ... and
+    ``capacity``."""
+    out = [1]
+    while out[-1] < capacity:
+        out.append(min(2 * out[-1], capacity))
+    return out
+
+
+def pad_log(xs, ys, bucket: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A host log ``xs (n, d)``, ``ys (n,)`` zero-padded to ``bucket`` ticks,
+    and the ``(bucket,)`` mask that marks the ``n`` real ones."""
+    xs, ys = np.asarray(xs), np.asarray(ys)
+    n = len(ys)
+    xs_p = np.zeros((bucket, xs.shape[-1]), xs.dtype)
+    ys_p = np.zeros((bucket,), ys.dtype)
+    xs_p[:n], ys_p[:n] = xs, ys
+    return xs_p, ys_p, np.arange(bucket) < n
+
+
+def _gate(mask: jax.Array, new, old):
+    """``new`` where ``mask`` (leading axes) is set, else ``old``."""
+    def leaf(a, b):
+        m = mask.reshape(mask.shape + (1,) * (a.ndim - mask.ndim))
+        return jnp.where(m, a, b)
+
+    return jax.tree.map(leaf, new, old)
+
+
+def gated_run(step_fn: Callable, state, xs, ys, mask):
+    """Sequential replay ``(state, x, y) -> (state, out)`` over a padded
+    log: a masked-off tick leaves every state leaf as it was (the chunk
+    kernels' masked tick), so the real ticks run exactly as unpadded."""
+
+    def body(s, xym):
+        x, y, m = xym
+        return _gate(m, step_fn(s, x, y)[0], s), None
+
+    return jax.lax.scan(body, state, (xs, ys, jnp.asarray(mask)))[0]
+
+
+def _ticks(xs: jax.Array, mask) -> jax.Array:
+    """Real ticks of a (possibly padded) log, as the state's int32 step."""
+    if mask is None:
+        return jnp.asarray(xs.shape[0], jnp.int32)
+    return jnp.sum(jnp.asarray(mask), dtype=jnp.int32)
+
+
 def replay_klms(
     rff: FeatureLike,
     xs: jax.Array,
@@ -327,6 +399,7 @@ def replay_klms(
     normalized: bool = False,
     eps: float = 1e-6,
     kernel_mode: str = "auto",
+    mask: Optional[jax.Array] = None,
 ) -> LMSState:
     """Rebuild a KLMS state from a replay log ``xs (T, d)``, ``ys (T,)``.
 
@@ -342,10 +415,21 @@ def replay_klms(
     Non-sequential modes match the sequential trajectory to reassociation
     rounding (pinned in tests/test_replay.py), not bitwise — composing
     ``A_t`` products reorders the floating-point work by design.
+
+    ``mask`` ``(T,)`` (``"scan"`` / ``"sequential"``): ticks where it is
+    False are padding and replay as the identity (:func:`pad_log`).
     """
     if state is None:
         state = rff_klms_init(rff.num_features, feature_dtype(rff))
+    if mask is not None and mode == "blocked":
+        raise ValueError("the blocked replay takes no tick mask")
     if mode == "sequential":
+        if mask is not None:
+            step = rff_nklms_step if normalized else rff_klms_step
+            hp = (mu, eps) if normalized else (mu,)
+            return gated_run(
+                lambda s, x, y: step(s, (x, y), rff, *hp), state, xs, ys, mask
+            )
         final, _ = rff_klms_run(
             rff, xs, ys, mu, state=state, normalized=normalized
         )
@@ -359,6 +443,11 @@ def replay_klms(
         to_el = nklms_to_element if normalized else klms_to_element
         args = (mu, eps) if normalized else (mu,)
         elements = to_el(z, ys, *args)
+        if mask is not None:
+            elements = _gate(
+                jnp.asarray(mask), elements,
+                affine_identity(z.shape[-1], z.dtype),
+            )
         composed = _last(jax.lax.associative_scan(affine_combine, elements))
     elif mode == "blocked":
         a, v = ops.rff_klms_chunk_elements(
@@ -372,7 +461,7 @@ def replay_klms(
         raise ValueError(f"unknown replay mode {mode!r}")
     return LMSState(
         theta=affine_apply(composed, state.theta),
-        step=state.step + xs.shape[0],
+        step=state.step + _ticks(xs, mask),
     )
 
 
@@ -386,6 +475,7 @@ def replay_krls(
     mode: str = "scan",
     chunk: Optional[int] = None,
     kernel_mode: str = "auto",
+    mask: Optional[jax.Array] = None,
 ) -> RLSState:
     """Rebuild a KRLS state from a replay log ``xs (T, d)``, ``ys (T,)``.
 
@@ -394,9 +484,18 @@ def replay_krls(
     exact inversion order matters. Scan modes accumulate the information
     form and invert ONCE; they track the sequential trajectory to solver
     accuracy (<= 1e-5 f32 / 1e-8 f64 over >= 1024 ticks, pinned in
-    tests/test_replay.py).
+    tests/test_replay.py). ``mask`` as :func:`replay_klms`.
     """
+    if mask is not None and mode == "blocked":
+        raise ValueError("the blocked replay takes no tick mask")
     if mode == "sequential":
+        if mask is not None:
+            if state is None:
+                state = rff_krls_init(rff.num_features, lam, feature_dtype(rff))
+            return gated_run(
+                lambda s, x, y: rff_krls_step(s, (x, y), rff, beta),
+                state, xs, ys, mask,
+            )
         final, _ = rff_krls_run(
             rff, xs, ys, lam=lam, beta=beta, state=state
         )
@@ -409,6 +508,11 @@ def replay_krls(
         fm = rff if tf is None else tf
         z = featurize(fm, xs)  # (T, D) — one GEMM
         elements = krls_to_element(z, ys, beta)
+        if mask is not None:
+            elements = _gate(
+                jnp.asarray(mask), elements,
+                decay_identity(z.shape[-1], z.dtype),
+            )
         composed = _last(jax.lax.associative_scan(decay_combine, elements))
     elif mode == "blocked":
         g, phi, r = ops.rff_krls_chunk_elements(
@@ -425,6 +529,6 @@ def replay_krls(
         # Fresh start: Phi_0 = lam I exactly — no inversion needed.
         phi0 = lam * jnp.eye(dfeat, dtype=dtype)
         phi, r = decay_apply(composed, phi0, jnp.zeros((dfeat,), dtype))
-        return _decay_to_rls(phi, r, jnp.asarray(xs.shape[0], jnp.int32))
+        return _decay_to_rls(phi, r, _ticks(xs, mask))
     final = _decay_apply_state(composed, state)
-    return final._replace(step=state.step + xs.shape[0])
+    return final._replace(step=state.step + _ticks(xs, mask))

@@ -66,6 +66,7 @@ from repro.core.bank import (
 )
 from repro.core.klms import LMSState, StepOut
 from repro.core.krls import RLSState
+from repro.core.scan import pad_log, replay_bucket, replay_buckets
 from repro.core.learner import (
     OnlineLearner,
     ald_krls_learner,
@@ -113,6 +114,12 @@ _HP_DEFAULTS = dict(
     nu=5e-4,       # ald novelty threshold
     capacity=256,  # qklms / ald dictionary capacity
 )
+
+# Entries per tenant in a policy server's replay log when none is given.
+_POLICY_LOG_CAPACITY = 256
+
+# Replay schedules that take a tick mask, so logs replay at padded buckets.
+_PADDED_REPLAY = ("scan", "sequential")
 
 
 # ---------------------------------------------------------------------------
@@ -448,10 +455,11 @@ class Server:
 
     Metrics (``self.metrics``): counters ``requests.write`` /
     ``requests.read`` / ``bank.hits`` / ``bank.misses`` / ``evictions`` /
-    ``readmissions`` / ``admission.rejects`` / ``read.cold`` /
-    ``resizes``, gauge ``queue.backlog`` (set when :meth:`observability`
-    exports, not per arrival), histograms ``latency.write_us`` /
-    ``latency.read_us``.
+    ``readmissions`` / ``replay.ticks`` / ``replay.pad_ticks`` (a
+    readmission's logged ticks, and the masked ticks padding its log to a
+    bucket) / ``admission.rejects`` / ``read.cold`` / ``resizes``, gauge
+    ``queue.backlog`` (set when :meth:`observability` exports, not per
+    arrival), histograms ``latency.write_us`` / ``latency.read_us``.
 
     Observability (``make_server(trace=..., probe=...)``): a Tracer
     records nested ``serve.*`` / ``queue.*`` / ``snapshot.*`` /
@@ -481,6 +489,7 @@ class Server:
         probe: Union[bool, dict, None] = None,
         recovery: Optional[RecoveryPolicy] = None,
         wal: Optional[DurableLog] = None,
+        padded_replay: bool = False,
     ):
         self._inner = inner
         self.learner = learner
@@ -495,6 +504,8 @@ class Server:
         self.tracer = tracer
         self.wal = wal
         self._wal_suspended = False
+        # Readmissions replay their log padded to a bucket of its ring.
+        self._padded_replay = padded_replay
         # Expected-ticks ledger, slot-keyed: observations this facade put
         # on the queue that the bank is on the hook to train. The
         # ``ticks_lag`` probe compares it against backlog + the state's
@@ -513,7 +524,9 @@ class Server:
         if policy is not None:
             # Tenant-ID-keyed logs (ids are unbounded in policy mode); the
             # inner slot-indexed log stays disabled.
-            self.log = ReplayLog(0, log_capacity or 256, inner.queue._dtype)
+            self.log = ReplayLog(
+                0, log_capacity or _POLICY_LOG_CAPACITY, inner.queue._dtype
+            )
             if policy.cost_fn is None:
                 policy.cost_fn = self._rebuild_cost
         else:
@@ -721,17 +734,13 @@ class Server:
             self.metrics.counter("bank.hits").inc()
         else:
             self.metrics.counter("bank.misses").inc()
-            decision = pol.admit(tenant)
+            decision = self._admit(tenant)
             if decision.action == "reject":
                 # Logged, not trained: the history is intact for a later
                 # admission, but the bank spends nothing on this tenant.
                 self.metrics.counter("admission.rejects").inc()
                 self.log.append(tenant, x, y)
                 return
-            if decision.action == "evict":
-                self.metrics.counter("evictions").inc()
-                self._inner.release_slot(decision.slot)
-                self._expected[decision.slot] = 0
             slot = decision.slot
             self._install(tenant, slot)
         self.log.append(tenant, x, y)
@@ -750,19 +759,37 @@ class Server:
         elif self.log is not None:
             self.log.append(tenant, x, y)
 
+    def _admit(self, tenant: int, force: bool = False):
+        """Ask the policy for a slot for ``tenant``; when it evicts an
+        incumbent, release the victim's slot (a fresh row, published)."""
+        with _obtrace.span("policy.admit", tenant=tenant) as sp:
+            decision = self.policy.admit(tenant, force=force)
+            if sp is not None:
+                sp.attrs.update(action=decision.action, victim=decision.victim)
+        if decision.action == "evict":
+            self.metrics.counter("evictions").inc()
+            with _obtrace.span(
+                "serve.evict", tenant=decision.victim, slot=decision.slot
+            ):
+                self._inner.release_slot(decision.slot)
+            self._expected[decision.slot] = 0
+        return decision
+
     def _install(self, tenant: int, slot: int) -> int:
-        """Rebuild ``tenant``'s state from its log into ``slot``."""
+        """Rebuild ``tenant``'s state from its log into ``slot``: the log
+        replays padded to its bucket (``ticks`` real, ``bucket`` in all)."""
         n = self.log.size(tenant)
         if n:
+            bucket = (replay_bucket(n, self.log.capacity)
+                      if self._padded_replay else n)
             with _obtrace.span(
-                "serve.install", tenant=tenant, slot=slot, ticks=n
+                "serve.install", tenant=tenant, slot=slot, ticks=n,
+                bucket=bucket,
             ):
-                xs, ys = self.log.arrays(tenant)
-                self._inner.queue.state = self._inner._rebuild_fn(
-                    self._inner.queue.state, slot, xs, ys
-                )
+                self._inner.write_slot(slot, self.log.arrays(tenant))
                 self.metrics.counter("readmissions").inc()
-                self._inner.publish()
+                self.metrics.counter("replay.ticks").inc(n)
+                self.metrics.counter("replay.pad_ticks").inc(bucket - n)
         self._expected[slot] = n
         return n
 
@@ -908,11 +935,7 @@ class Server:
             if pol.lookup(tenant) is not None:
                 return 0
             pol.touch(tenant)
-            decision = pol.admit(tenant, force=True)
-            if decision.action == "evict":
-                self.metrics.counter("evictions").inc()
-                self._inner.release_slot(decision.slot)
-                self._expected[decision.slot] = 0
+            decision = self._admit(tenant, force=True)
             return self._install(tenant, decision.slot)
 
     def reset_tenant(self, tenant: int) -> int:
@@ -931,11 +954,7 @@ class Server:
             slot = self.policy.lookup(tenant)
             if slot is None:
                 return 0
-            inner = self._inner
-            dropped = inner.queue.drop_pending(slot)
-            inner._arrival_times[slot].clear()
-            inner.queue.state = inner._evict_fn(inner.queue.state, slot)
-            inner.publish()
+            dropped = self._inner.release_slot(slot)
             self._expected[slot] = 0
             return dropped
 
@@ -1041,6 +1060,34 @@ class Server:
         return float(n) * cap
 
 
+@functools.partial(
+    jax.jit, static_argnames=("learner", "input_dim", "hp", "mode")
+)
+def _install_row(bank_state, slot, xs, ys, mask, feature_map, *, learner,
+                 input_dim, hp, mode):
+    """Rebuild one tenant from a padded log and write it into ``slot``. The
+    feature map is an argument, so servers of one shape share programs."""
+    lrn = build_learner(learner, feature_map, input_dim, **dict(hp))
+    row = lrn.rebuild(xs, ys, mode=mode, mask=mask)
+    return set_tenant_row(bank_state, slot, row)
+
+
+def _padded_rebuild_fn(learner: str, feature_map, input_dim, hp: dict,
+                       rebuild_mode: str, log_cap: int) -> Callable:
+    """A policy server's rebuild hook ``(state, slot, xs, ys) -> state``:
+    the log replays padded to its bucket (``core.scan.pad_log``) through
+    one compiled program per bucket, which also writes the row."""
+    static = dict(learner=learner, input_dim=input_dim,
+                  hp=tuple(sorted(hp.items())), mode=rebuild_mode)
+
+    def rebuild_fn(bank_state, slot, xs, ys):
+        bucket = replay_bucket(len(ys), log_cap)
+        return _install_row(bank_state, slot, *pad_log(xs, ys, bucket),
+                            feature_map, **static)
+
+    return rebuild_fn
+
+
 def _resolve_policy(policy, bank: int) -> Optional[SlotPolicy]:
     if policy is None:
         return None
@@ -1099,6 +1146,11 @@ def make_server(
         old snapshot-server contract).
       rebuild_mode: replay schedule for readmissions ("scan" / "blocked"
         / "sequential"; dictionary learners always replay sequentially).
+        With a policy, under "scan" and "sequential", a log replays padded
+        to its bucket (the next power of two, at most ``log_capacity``)
+        with the padding masked, and every bucket's rebuild, and the row
+        write after it, is compiled before the server is returned: nothing
+        compiles when a tenant is readmitted while serving.
       policy: None (tenant == slot), a scorer name ("lru" / "lfu" /
         "cost"), a ``SlotPolicy`` kwargs dict, or a ready instance.
       auto_resize: apply the policy's pow2 ``suggest_size`` after submits.
@@ -1140,11 +1192,18 @@ def make_server(
         adaptive=adaptive, state=state, input_dim=input_dim, **hp,
     )
 
-    def rebuild_fn(bank_state, slot, xs, ys):
-        row = lrn.rebuild(
-            jnp.asarray(xs), jnp.asarray(ys), mode=rebuild_mode
+    log_cap = log_capacity or _POLICY_LOG_CAPACITY
+    padded = policy is not None and rebuild_mode in _PADDED_REPLAY
+    if padded:
+        rebuild_fn = _padded_rebuild_fn(
+            learner, feature_map, input_dim, h, rebuild_mode, log_cap
         )
-        return set_tenant_row(bank_state, slot, row)
+    else:
+        def rebuild_fn(bank_state, slot, xs, ys):
+            row = lrn.rebuild(
+                jnp.asarray(xs), jnp.asarray(ys), mode=rebuild_mode
+            )
+            return set_tenant_row(bank_state, slot, row)
 
     if learner == "krls":
         def evict_fn(bank_state, slot):
@@ -1192,7 +1251,7 @@ def make_server(
         tracer = _obtrace.Tracer() if trace else None
     else:
         tracer = _obtrace.Tracer(capacity=int(trace))
-    return Server(
+    server = Server(
         inner,
         learner=learner,
         lrn=lrn,
@@ -1206,4 +1265,14 @@ def make_server(
         probe=probe,
         recovery=rec,
         wal=wal_log,
+        padded_replay=padded,
     )
+    if padded:
+        # Compile every bucket's rebuild and row write, and the eviction's
+        # row write, now: the results are dropped and the state is as it was.
+        state, d = queue.state, queue.input_dim
+        for b in replay_buckets(log_cap):
+            rebuild_fn(state, 0, np.zeros((b, d), queue._dtype),
+                       np.zeros((b,), queue._dtype))
+        jax.block_until_ready(evict_fn(state, 0))
+    return server

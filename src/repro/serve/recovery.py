@@ -471,12 +471,8 @@ class RecoveryPolicy:
             # slot's backlog and replay the whole history into the slot.
             inner.queue.drop_pending(ep.slot)
             inner._arrival_times[ep.slot].clear()
-            xs, ys = server.log.arrays(ep.tenant)
-            inner.queue.state = inner._rebuild_fn(
-                inner.queue.state, ep.slot, xs, ys
-            )
-            inner.publish()
-            replayed = len(ys)
+            inner.write_slot(ep.slot, server.log.arrays(ep.tenant))
+            replayed = server.log.size(ep.tenant)
         server._expected[ep.slot] = replayed
 
     def _verify(self, ep: _Episode) -> bool:

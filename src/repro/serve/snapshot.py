@@ -407,9 +407,8 @@ class SnapshotServer:
         """
         dropped = self.queue.drop_pending(tenant)
         self._arrival_times[tenant].clear()
-        self.queue.state = self._evict_fn(self.queue.state, tenant)
         self._evicted.add(tenant)
-        self.publish()
+        self.write_slot(tenant)
         return dropped
 
     def readmit(self, tenant: int, mode: Optional[str] = None) -> int:
@@ -426,27 +425,25 @@ class SnapshotServer:
         """
         if tenant not in self._evicted:
             raise ValueError(f"tenant {tenant} is not evicted")
-        replayed = 0
-        if self.log is not None and self.log.size(tenant):
-            if self._rebuild_fn is None:
-                raise ValueError(
-                    "readmit with a non-empty log needs a rebuild_fn "
-                    "(use the klms/krls factories or pass one)"
-                )
-            xs, ys = self.log.arrays(tenant)
-            with _trace.span(
-                "snapshot.rebuild",
-                tenant=tenant,
-                ticks=len(ys),
-                complete=self.log.complete(tenant),
-            ):
-                self.queue.state = self._rebuild_fn(
-                    self.queue.state, tenant, xs, ys
-                )
-            replayed = len(ys)
+        logged = self.log is not None and self.log.size(tenant)
+        if logged and self._rebuild_fn is None:
+            raise ValueError(
+                "readmit with a non-empty log needs a rebuild_fn "
+                "(use the klms/krls factories or pass one)"
+            )
         self._evicted.discard(tenant)
-        self.publish()
-        return replayed
+        if not logged:
+            self.publish()
+            return 0
+        log = self.log.arrays(tenant)
+        with _trace.span(
+            "snapshot.rebuild",
+            tenant=tenant,
+            ticks=len(log[1]),
+            complete=self.log.complete(tenant),
+        ):
+            self.write_slot(tenant, log)
+        return len(log[1])
 
     def release_slot(self, slot: int) -> int:
         """Release one bank slot *without* entering the evicted set (the
@@ -458,10 +455,20 @@ class SnapshotServer:
         the slot-keyed one. Returns the dropped pending count."""
         dropped = self.queue.drop_pending(slot)
         self._arrival_times[slot].clear()
-        self.queue.state = self._evict_fn(self.queue.state, slot)
         self._evicted.discard(slot)
-        self.publish()
+        self.write_slot(slot)
         return dropped
+
+    def write_slot(self, slot: int, log=None) -> None:
+        """Replace one slot's row of the live state and publish: the row
+        rebuilt from ``log`` (``(xs, ys)``, through ``rebuild_fn``), or a
+        fresh row (``evict_fn``) without one. Every row write of the
+        lifecycle goes through here."""
+        if log is None:
+            self.queue.state = self._evict_fn(self.queue.state, slot)
+        else:
+            self.queue.state = self._rebuild_fn(self.queue.state, slot, *log)
+        self.publish()
 
     def reset_tenant(self, tenant: int) -> int:
         """Reset ONE tenant to a fresh slot: drop its pending
@@ -474,9 +481,8 @@ class SnapshotServer:
         self._arrival_times[tenant].clear()
         if self.log is not None:
             self.log.clear(tenant)
-        self.queue.state = self._evict_fn(self.queue.state, tenant)
         self._evicted.discard(tenant)
-        self.publish()
+        self.write_slot(tenant)
         return dropped
 
     def move_slot(self, src: int, dst: int) -> None:
