@@ -47,11 +47,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bank import (
+    _fresh_row,
     bank_init,
     bank_run,
     bank_size,
     bank_step,
-    evict_tenant,
     klms_bank_chunk_step,
     klms_bank_init,
     klms_bank_run,
@@ -1072,6 +1072,24 @@ def _install_row(bank_state, slot, xs, ys, mask, feature_map, *, learner,
     return set_tenant_row(bank_state, slot, row)
 
 
+def _parked_row(learner: str, bank_state, lam):
+    """The row an eviction parks, built once per server: ``P_0 = I/lam``,
+    zero theta and step for KRLS (``core.bank.evict_tenant``'s row), zeros
+    for every other family."""
+    if learner == "krls":
+        return _fresh_row(bank_state, lam)
+    return jax.tree.map(
+        lambda a: jnp.zeros(a.shape[1:], a.dtype), bank_state
+    )
+
+
+@jax.jit
+def _park_row(bank_state, slot, row):
+    """Write ``row`` into ``slot``: one program for every slot (``slot`` is
+    an int32 operand) and, being pytree-generic, every family."""
+    return set_tenant_row(bank_state, slot, row)
+
+
 def _padded_rebuild_fn(learner: str, feature_map, input_dim, hp: dict,
                        rebuild_mode: str, log_cap: int) -> Callable:
     """A policy server's rebuild hook ``(state, slot, xs, ys) -> state``:
@@ -1205,17 +1223,10 @@ def make_server(
             )
             return set_tenant_row(bank_state, slot, row)
 
-    if learner == "krls":
-        def evict_fn(bank_state, slot):
-            return evict_tenant(bank_state, slot, lam=h["lam"])
-    elif learner in ("qklms", "ald"):
-        def evict_fn(bank_state, slot):
-            fresh = jax.tree.map(
-                jnp.zeros_like, tenant_row(bank_state, slot)
-            )
-            return set_tenant_row(bank_state, slot, fresh)
-    else:
-        evict_fn = evict_tenant
+    parked = _parked_row(learner, queue.state, h["lam"])
+
+    def evict_fn(bank_state, slot):
+        return _park_row(bank_state, np.int32(slot), parked)
 
     rec: Optional[RecoveryPolicy] = None
     if recovery:

@@ -45,6 +45,7 @@ from repro.core.learner import (
     qklms_learner,
 )
 from repro.core.rff import sample_rff
+from repro.serve import make_server
 from repro.serve.snapshot import (
     ReplayLog,
     klms_snapshot_server,
@@ -331,6 +332,81 @@ def test_reset_tenant_clears_stale_log_truncation_flags():
         )
     )
     assert srv.log.complete(1) == ctl.log.complete(1)
+
+
+# -- make_server's eviction: one compiled row write ------------------------
+
+_LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_SERVER_HP = {
+    "klms": dict(mu=0.3),
+    "nklms": dict(mu=0.3),
+    "krls": dict(lam=0.1, beta=0.99),
+    "qklms": dict(sigma=1.0, mu=0.3, quant_eps=0.1, capacity=32),
+    "ald": dict(sigma=1.0, nu=5e-4, capacity=32),
+}
+
+
+def _old_evict(family, state, slot):
+    """The row write each family's eviction made before it was compiled."""
+    if family in ("qklms", "ald"):
+        zeros = jax.tree.map(jnp.zeros_like, tenant_row(state, slot))
+        return set_tenant_row(state, slot, zeros)
+    return evict_tenant(state, slot, lam=_SERVER_HP[family].get("lam", 1e-4))
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_make_server_eviction_is_one_program_bitwise_the_eager_write(family):
+    """The server's eviction parks bitwise the row ``core.bank.evict_tenant``
+    (zeros for the dictionary families) parks, at the first, second and
+    last slot, through one program lowered once for every slot."""
+    bank = 6
+    srv = make_server(family, feature_map=_RFF, bank=bank, chunk=4,
+                      **_SERVER_HP[family])
+    _drive(srv, _obs(3, 60, tenants=bank))
+    state = srv.queue.state
+    evict_fn = srv._inner._evict_fn
+    jax.clear_caches()  # families of one state type share the program
+    lowerings = []
+
+    def on(name, _secs, **_kw):
+        if name == _LOWERING:
+            lowerings.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        got = {slot: evict_fn(state, slot) for slot in (0, 1, bank - 1)}
+        jax.block_until_ready(got)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert len(lowerings) == 1
+    for slot, new in got.items():
+        assert not all(
+            bool(jnp.array_equal(a, b)) for a, b in zip(
+                jax.tree.leaves(tenant_row(state, slot)),
+                jax.tree.leaves(tenant_row(new, slot)),
+            )
+        ), slot  # the slot was trained, so the write is seen
+        _assert_trees_equal(new, _old_evict(family, state, slot))
+
+
+@pytest.mark.parametrize("policy", [None, "lru"])
+def test_snapshot_taken_before_an_eviction_keeps_its_row(policy):
+    """The eviction writes a new bank and leaves the old one alone (no
+    donation): a reader holding the snapshot published before it still
+    reads the evicted tenant's trained row."""
+    srv = make_server("krls", feature_map=_RFF, bank=4, chunk=4,
+                      policy=policy, **_SERVER_HP["krls"])
+    _drive(srv, _obs(5, 40, tenants=3))
+    slot = 1 if policy is None else srv.policy.lookup(1)
+    held = srv.snapshot
+    before = jax.tree.map(np.array, tenant_row(held.state, slot))
+    assert srv.evict(1) == 0
+    assert srv.snapshot.version > held.version
+    _assert_trees_equal(srv.snapshot.state,
+                        _old_evict("krls", held.state, slot))
+    after = jax.tree.map(np.asarray, tenant_row(held.state, slot))
+    _assert_trees_equal(after, before)
+    assert int(before.step) > 0
 
 
 # -- f64 (subprocess: conftest pins x64 off) --------------------------------

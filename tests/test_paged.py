@@ -7,8 +7,9 @@ from their replay logs.
 * Padded replays (``core.scan.pad_log``) against unpadded ones, for every
   log length up to the ring's capacity, in ``"scan"`` and ``"sequential"``
   modes, for KRLS and KLMS; the rebuilt ``step`` counts real ticks only.
-* A policy server compiles every rebuild while ``make_server`` runs:
-  readmissions at many log lengths compile nothing afterwards.
+* A policy server compiles every rebuild and the eviction's row write
+  while ``make_server`` runs: readmissions at many log lengths and the
+  evictions before them compile nothing afterwards.
 * The lifecycle's spans and counters: ``policy.admit`` and ``serve.evict``
   nest under ``serve.submit``, ``serve.install`` carries ``ticks`` and
   ``bucket``, and ``replay.ticks`` + ``replay.pad_ticks`` is the buckets'
@@ -227,6 +228,7 @@ def test_policy_server_compiles_nothing_after_make_server():
         if name == LOWERING:
             lowerings.append(name)
 
+    evictions = srv.metrics.count("evictions")
     jax.monitoring.register_event_duration_secs_listener(on)
     try:
         for k, x, y in zip(keys[1:].tolist(), xs[1:], ys[1:]):
@@ -237,6 +239,8 @@ def test_policy_server_compiles_nothing_after_make_server():
     lengths = {s.attrs["ticks"] for s in tracer.spans()
                if s.name == "serve.install"}
     assert len(lengths) >= 10, sorted(lengths)
+    # Evictions ran in the loop, so their row write is covered too.
+    assert srv.metrics.count("evictions") > evictions
     assert not lowerings
 
 
