@@ -31,8 +31,6 @@ DEPRECATED = [
     "make_chunked_krls_bank_server",
     "klms_micro_batch_queue",
     "krls_micro_batch_queue",
-    "klms_snapshot_server",
-    "krls_snapshot_server",
     "reset_tenants",
     "reset_krls_tenants",
 ]
@@ -41,7 +39,6 @@ DEPRECATED = [
 EXEMPT = (
     "src/repro/serve/bank_loop.py",
     "src/repro/serve/queue.py",
-    "src/repro/serve/snapshot.py",
     "src/repro/serve/api.py",
     "src/repro/serve/__init__.py",
     "tests/",

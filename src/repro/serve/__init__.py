@@ -36,7 +36,6 @@ from repro.serve.queue import (
     make_chunked_bank_server,
     make_chunked_krls_bank_server,
 )
-from repro.serve.snapshot import klms_snapshot_server, krls_snapshot_server
 
 __all__ = [
     "generate",
@@ -78,6 +77,4 @@ __all__ = [
     "make_chunked_krls_bank_server",
     "klms_micro_batch_queue",
     "krls_micro_batch_queue",
-    "klms_snapshot_server",
-    "krls_snapshot_server",
 ]

@@ -32,8 +32,9 @@ admit (possibly evicting the coldest incumbent, subject to the admission
 floor), rejected arrivals are logged-not-trained, readmissions rebuild
 from the per-tenant replay log through the parallel-in-time engine, and
 ``resize`` grows/shrinks the bank in pow2 steps with bitwise row
-migration. Without a policy, tenant ids ARE slot indices (the pre-policy
-contract, equivalence-tested against the deprecated factories).
+migration. Without a policy, tenant ids ARE slot indices: an identity
+router (serve/policy.py) stands in for the policy, so both kinds of server
+run one tenant lifecycle over one tenant-keyed replay log.
 """
 from __future__ import annotations
 
@@ -81,7 +82,7 @@ from repro.obs import probes as _probes
 from repro.obs import telemetry as _telemetry
 from repro.obs import trace as _obtrace
 from repro.serve.metrics import MetricsRegistry
-from repro.serve.policy import SlotPolicy
+from repro.serve.policy import IdentityRouter, SlotPolicy
 from repro.serve.queue import MicroBatchQueue
 from repro.serve.recovery import DurableLog, RecoveryPolicy, save_checkpoint
 from repro.serve.snapshot import ReplayLog, SnapshotServer, predict_row
@@ -448,10 +449,13 @@ class Server:
     policy, and metrics behind a single learner-agnostic surface.
 
     Built by :func:`make_server`. Without a policy, ``tenant`` arguments
-    are bank-slot indices in ``[0, slots)`` — exactly the pre-facade
-    :class:`~repro.serve.snapshot.SnapshotServer` contract. With a policy,
-    ``tenant`` is an arbitrary id; the Server runs the bank as a cache
-    (see module docstring) and ``resize`` manages capacity in pow2 steps.
+    are bank-slot indices in ``[0, slots)``, routed by an
+    :class:`~repro.serve.policy.IdentityRouter`: ``evict`` puts a tenant
+    out (its writes are logged, not trained) until ``readmit``. With a
+    policy, ``tenant`` is an arbitrary id; the Server runs the bank as a
+    cache (see module docstring) and ``resize`` manages capacity in pow2
+    steps. ``policy`` is the :class:`~repro.serve.policy.SlotPolicy`, or
+    None without one.
 
     Metrics (``self.metrics``): counters ``requests.write`` /
     ``requests.read`` / ``bank.hits`` / ``bank.misses`` / ``evictions`` /
@@ -521,16 +525,19 @@ class Server:
             inner.queue.attach_probe(_probes.stats_tap)
         else:
             self.probe = None
-        if policy is not None:
-            # Tenant-ID-keyed logs (ids are unbounded in policy mode); the
-            # inner slot-indexed log stays disabled.
-            self.log = ReplayLog(
-                0, log_capacity or _POLICY_LOG_CAPACITY, inner.queue._dtype
-            )
-            if policy.cost_fn is None:
-                policy.cost_fn = self._rebuild_cost
-        else:
-            self.log = inner.log
+        self._router = policy or IdentityRouter(inner.queue.num_tenants)
+        # One tenant-keyed log; None (no log) restarts readmissions cold.
+        self.log = (
+            ReplayLog(log_capacity, inner.queue._dtype)
+            if log_capacity is not None
+            else None
+        )
+        # Per-request metrics, looked up once rather than on every request.
+        self._writes = self.metrics.counter("requests.write")
+        self._reads = self.metrics.counter("requests.read")
+        self._hits = self.metrics.counter("bank.hits")
+        self._write_us = self.metrics.histogram("latency.write_us")
+        self._read_us = self.metrics.histogram("latency.read_us")
         # A pristine row captured before any training: the pad row for
         # bank growth (theta 0 / P_0 = I/lam / zeroed dictionaries).
         self._fresh_row = tenant_row(inner.queue.state, 0)
@@ -568,14 +575,15 @@ class Server:
 
     @property
     def resident(self) -> dict:
-        """tenant -> slot map (identity without a policy)."""
-        if self.policy is None:
-            return {t: t for t in range(self.slots)}
-        return self.policy.resident
+        """tenant -> slot map (identity without a policy, less the tenants
+        put out by ``evict``)."""
+        return self._router.resident
 
     @property
-    def evicted(self):
-        return self._inner.evicted
+    def evicted(self) -> frozenset:
+        """Tenants put out by ``evict`` on a server without a policy (a
+        policy's non-resident tenants are admitted on their next write)."""
+        return self._router.evicted
 
     @property
     def snapshot_server(self) -> SnapshotServer:
@@ -703,7 +711,7 @@ class Server:
         rejecting through the policy when one is configured)."""
         t0 = self._lat()
         with self._act(), _obtrace.span("serve.submit", tenant=tenant):
-            self.metrics.counter("requests.write").inc()
+            self._writes.inc()
             if self.wal is not None and not self._wal_suspended:
                 self.wal.append(tenant, x, y)
             if (
@@ -711,27 +719,19 @@ class Server:
                 and tenant in self.recovery.quarantined
             ):
                 self._quarantined_submit(tenant, x, y)
-            elif self.policy is None:
-                if tenant not in self._inner._evicted:
-                    self._note_queued(tenant)
-                self._inner.submit(tenant, x, y)
             else:
-                self._policy_submit(tenant, x, y)
+                self._routed_submit(tenant, x, y)
             self._probe_update()
-            self.metrics.histogram("latency.write_us").observe(
-                (self._lat() - t0) * 1e6
-            )
-            if self.policy is not None and self.auto_resize:
+            self._write_us.observe((self._lat() - t0) * 1e6)
+            if self.auto_resize:
                 target = self.policy.suggest_size()
                 if target != self.slots:
                     self.resize(target)
 
-    def _policy_submit(self, tenant: int, x, y) -> None:
-        pol = self.policy
-        pol.touch(tenant)
-        slot = pol.lookup(tenant)
+    def _routed_submit(self, tenant: int, x, y) -> None:
+        slot = self._router.route(tenant)
         if slot is not None:
-            self.metrics.counter("bank.hits").inc()
+            self._hits.inc()
         else:
             self.metrics.counter("bank.misses").inc()
             decision = self._admit(tenant)
@@ -739,11 +739,13 @@ class Server:
                 # Logged, not trained: the history is intact for a later
                 # admission, but the bank spends nothing on this tenant.
                 self.metrics.counter("admission.rejects").inc()
-                self.log.append(tenant, x, y)
+                if self.log is not None:
+                    self.log.append(tenant, x, y)
                 return
             slot = decision.slot
             self._install(tenant, slot)
-        self.log.append(tenant, x, y)
+        if self.log is not None:
+            self.log.append(tenant, x, y)
         self._note_queued(slot)
         self._inner.submit(slot, x, y)
 
@@ -753,17 +755,15 @@ class Server:
         rest of the history. The policy clock still ticks so admission
         ordering stays deterministic across the episode."""
         self.metrics.counter("recovery.deferred").inc()
-        if self.policy is not None:
-            self.policy.touch(tenant)
-            self.log.append(tenant, x, y)
-        elif self.log is not None:
+        self._router.touch(tenant)
+        if self.log is not None:
             self.log.append(tenant, x, y)
 
     def _admit(self, tenant: int, force: bool = False):
-        """Ask the policy for a slot for ``tenant``; when it evicts an
+        """Ask the router for a slot for ``tenant``; when it evicts an
         incumbent, release the victim's slot (a fresh row, published)."""
         with _obtrace.span("policy.admit", tenant=tenant) as sp:
-            decision = self.policy.admit(tenant, force=force)
+            decision = self._router.admit(tenant, force=force)
             if sp is not None:
                 sp.attrs.update(action=decision.action, victim=decision.victim)
         if decision.action == "evict":
@@ -777,8 +777,9 @@ class Server:
 
     def _install(self, tenant: int, slot: int) -> int:
         """Rebuild ``tenant``'s state from its log into ``slot``: the log
-        replays padded to its bucket (``ticks`` real, ``bucket`` in all)."""
-        n = self.log.size(tenant)
+        replays padded to its bucket (``ticks`` real, ``bucket`` in all).
+        Without a log, or with an empty one, the slot's fresh row stays."""
+        n = self.log.size(tenant) if self.log is not None else 0
         if n:
             bucket = (replay_bucket(n, self.log.capacity)
                       if self._padded_replay else n)
@@ -829,24 +830,21 @@ class Server:
     def predict(self, tenant: int, xs) -> jax.Array:
         """Serve queries for one tenant from the frozen read replica.
 
-        ``xs`` is ``(d,)`` (scalar out) or ``(Q, d)`` (``(Q,)`` out). In
-        policy mode a non-resident tenant gets the *cold* prediction
-        (fresh-state zeros) — reads never admit, so the read path stays
-        O(1) regardless of replay-log depth.
+        ``xs`` is ``(d,)`` (scalar out) or ``(Q, d)`` (``(Q,)`` out). A
+        tenant that is not resident (or was put out by ``evict``) gets the
+        *cold* prediction (fresh-state zeros) — reads never admit, so the
+        read path stays O(1) regardless of replay-log depth.
         """
         t0 = self._lat()
         with self._act(), _obtrace.span("serve.predict", tenant=tenant):
-            self.metrics.counter("requests.read").inc()
+            self._reads.inc()
             if (
                 self.recovery is not None
                 and tenant in self.recovery.quarantined
             ):
                 pred = self._quarantined_predict(tenant, xs)
-            elif self.policy is None:
-                pred = self._slot_predict(tenant, xs)
             else:
-                self.policy.touch(tenant)
-                slot = self.policy.lookup(tenant)
+                slot = self._router.route(tenant)
                 if slot is None:
                     self.metrics.counter("bank.misses").inc()
                     self.metrics.counter("read.cold").inc()
@@ -854,11 +852,9 @@ class Server:
                     shape = () if xq.ndim == 1 else (xq.shape[0],)
                     pred = jnp.zeros(shape, self._inner.queue._dtype)
                 else:
-                    self.metrics.counter("bank.hits").inc()
+                    self._hits.inc()
                     pred = self._slot_predict(slot, xs)
-            self.metrics.histogram("latency.read_us").observe(
-                (self._lat() - t0) * 1e6
-            )
+            self._read_us.observe((self._lat() - t0) * 1e6)
             return pred
 
     def _quarantined_predict(self, tenant: int, xs) -> jax.Array:
@@ -866,8 +862,7 @@ class Server:
         last-healthy replica row (cold zeros if it was never seen
         healthy) — the degraded slot is never read."""
         self.metrics.counter("read.quarantined").inc()
-        if self.policy is not None:
-            self.policy.touch(tenant)
+        self._router.touch(tenant)
         row = self.recovery.healthy_row(tenant)
         xq = jnp.asarray(xs)
         single = xq.ndim == 1
@@ -889,52 +884,45 @@ class Server:
         space) in one launch from the frozen replica -> ``(B, Q)``."""
         t0 = self._lat()
         with self._act(), _obtrace.span("serve.predict_block"):
-            self.metrics.counter("requests.read").inc()
+            self._reads.inc()
             if self._theta_family:
                 pred = self._inner.predict_block(xq)
             else:
                 pred = self._block_predict(
                     self._inner.snapshot.state, jnp.asarray(xq)
                 )
-            self.metrics.histogram("latency.read_us").observe(
-                (self._lat() - t0) * 1e6
-            )
+            self._read_us.observe((self._lat() - t0) * 1e6)
             return pred
 
     # -- lifecycle -----------------------------------------------------------
 
     def evict(self, tenant: int) -> int:
-        """Release ``tenant``'s slot. Returns dropped pending count."""
+        """Release ``tenant``'s slot: drop its backlog and park a fresh row.
+        The replay log keeps its history (dropped arrivals included) for
+        ``readmit``. Returns dropped pending count (0 when the tenant held
+        no slot)."""
         with self._act(), _obtrace.span("serve.evict", tenant=tenant):
-            if self.policy is None:
-                dropped = self._inner.evict(tenant)
-                self._expected[tenant] = 0
-            else:
-                slot = self.policy.release(tenant)
-                if slot is None:
-                    return 0
-                dropped = self._inner.release_slot(slot)
-                self._expected[slot] = 0
+            slot = self._router.release(tenant)
+            if slot is None:
+                return 0
+            dropped = self._inner.release_slot(slot)
+            self._expected[slot] = 0
             self.metrics.counter("evictions").inc()
             return dropped
 
     def readmit(self, tenant: int) -> int:
         """Re-admit ``tenant``, rebuilding its state from the replay log.
 
-        Policy mode bypasses the admission floor (an explicit readmit is
-        an operator decision), evicting the coldest incumbent if the bank
-        is full. Returns the number of replayed ticks.
+        An explicit readmit bypasses the admission floor (it is an operator
+        decision): a policy evicts the coldest incumbent if the bank is
+        full, and without a policy the tenant gets its own slot back.
+        Returns the number of replayed ticks (0 when already resident).
         """
         with self._act(), _obtrace.span("serve.readmit", tenant=tenant):
-            if self.policy is None:
-                n = self._inner.readmit(tenant)
-                self._expected[tenant] = n
-                self.metrics.counter("readmissions").inc()
-                return n
-            pol = self.policy
-            if pol.lookup(tenant) is not None:
+            router = self._router
+            if router.lookup(tenant) is not None:
                 return 0
-            pol.touch(tenant)
+            router.touch(tenant)
             decision = self._admit(tenant, force=True)
             return self._install(tenant, decision.slot)
 
@@ -942,16 +930,15 @@ class Server:
         """Reset ONE tenant to a fresh row — the O(1) last rung of the
         recovery ladder, also useful as an operator action. The tenant's
         replay history (and its ring-overflow flag) is forgotten with the
-        state; in policy mode a resident tenant keeps its slot. Returns
-        the dropped pending count."""
+        state; a resident tenant keeps its slot, and a tenant put out by
+        ``evict`` gets its slot back. Returns the dropped pending count."""
         with self._act(), _obtrace.span("serve.reset_tenant", tenant=tenant):
             self.metrics.counter("resets").inc()
-            if self.policy is None:
-                dropped = self._inner.reset_tenant(tenant)
-                self._expected[tenant] = 0
-                return dropped
-            self.log.clear(tenant)
-            slot = self.policy.lookup(tenant)
+            if self.log is not None:
+                self.log.clear(tenant)
+            if tenant in self.evicted:
+                self._router.admit(tenant, force=True)
+            slot = self._router.lookup(tenant)
             if slot is None:
                 return 0
             dropped = self._inner.release_slot(slot)
@@ -976,14 +963,9 @@ class Server:
             state = set_tenant_row(state, 0, self._fresh_row)
         self._inner.reset(state)
         self._expected.clear()
-        if self.policy is not None:
+        if self.log is not None:
             self.log.clear()
-            pol = self.policy
-            pol.clock = 0
-            pol.last_touch.clear()
-            pol.touches.clear()
-            pol._resident.clear()
-            pol.set_slots(bank_size(state))
+        self._router.reset(bank_size(state))
 
     # -- capacity ------------------------------------------------------------
 
@@ -996,7 +978,7 @@ class Server:
         ``tenant_row``/``set_tenant_row`` — surviving rows are
         bitwise-preserved (tested) — and slices the bank.
         """
-        if self.policy is None:
+        if not isinstance(self._router, SlotPolicy):
             raise ValueError("resize requires a policy tier")
         if new_slots < 1 or (new_slots & (new_slots - 1)):
             raise ValueError(f"new_slots must be a power of two, got {new_slots}")
@@ -1007,7 +989,7 @@ class Server:
             "serve.resize", slots=cur, new_slots=new_slots
         ):
             self.metrics.counter("resizes").inc()
-            pol, inner = self.policy, self._inner
+            pol, inner = self._router, self._inner
             if new_slots < cur:
                 while pol.occupancy > new_slots:
                     self.evict(pol.victim())
@@ -1106,9 +1088,7 @@ def _padded_rebuild_fn(learner: str, feature_map, input_dim, hp: dict,
     return rebuild_fn
 
 
-def _resolve_policy(policy, bank: int) -> Optional[SlotPolicy]:
-    if policy is None:
-        return None
+def _resolve_policy(policy, bank: int) -> SlotPolicy:
     if isinstance(policy, SlotPolicy):
         if policy.slots != bank:
             raise ValueError(
@@ -1160,8 +1140,8 @@ def make_server(
       precision / publish_every / age_watermark / size_watermark / clock:
         snapshot-tier knobs (serve/snapshot.py).
       log_capacity: per-tenant replay-log ring size. Policy mode defaults
-        it to 256; without a policy, None disables the lifecycle log (the
-        old snapshot-server contract).
+        it to 256; without a policy, None keeps no log (a readmitted
+        tenant restarts cold).
       rebuild_mode: replay schedule for readmissions ("scan" / "blocked"
         / "sequential"; dictionary learners always replay sequentially).
         With a policy, under "scan" and "sequential", a log replays padded
@@ -1171,7 +1151,8 @@ def make_server(
         compiles when a tenant is readmitted while serving.
       policy: None (tenant == slot), a scorer name ("lru" / "lfu" /
         "cost"), a ``SlotPolicy`` kwargs dict, or a ready instance.
-      auto_resize: apply the policy's pow2 ``suggest_size`` after submits.
+      auto_resize: apply the policy's pow2 ``suggest_size`` after submits
+        (ignored without a policy).
       metrics: a shared :class:`MetricsRegistry` (fresh one by default).
       state: initial bank state (fresh init by default).
       trace: request tracing — ``True`` for a fresh default
@@ -1210,11 +1191,16 @@ def make_server(
         adaptive=adaptive, state=state, input_dim=input_dim, **hp,
     )
 
-    log_cap = log_capacity or _POLICY_LOG_CAPACITY
-    padded = policy is not None and rebuild_mode in _PADDED_REPLAY
+    pol, padded = None, False
+    if policy is not None:
+        pol = _resolve_policy(policy, bank)
+        log_capacity = log_capacity or _POLICY_LOG_CAPACITY
+        padded = rebuild_mode in _PADDED_REPLAY
+    else:
+        auto_resize = False
     if padded:
         rebuild_fn = _padded_rebuild_fn(
-            learner, feature_map, input_dim, h, rebuild_mode, log_cap
+            learner, feature_map, input_dim, h, rebuild_mode, log_capacity
         )
     else:
         def rebuild_fn(bank_state, slot, xs, ys):
@@ -1242,7 +1228,6 @@ def make_server(
         wal_log = wal
     else:
         wal_log = DurableLog(wal)
-    pol = _resolve_policy(policy, bank)
     inner = SnapshotServer(
         queue,
         feature_map,
@@ -1252,7 +1237,6 @@ def make_server(
         age_watermark=age_watermark,
         size_watermark=size_watermark,
         clock=clock,
-        log_capacity=None if pol is not None else log_capacity,
         evict_fn=evict_fn,
         rebuild_fn=rebuild_fn,
     )
@@ -1278,11 +1262,13 @@ def make_server(
         wal=wal_log,
         padded_replay=padded,
     )
+    if pol is not None and pol.cost_fn is None:
+        pol.cost_fn = server._rebuild_cost
     if padded:
         # Compile every bucket's rebuild and row write, and the eviction's
         # row write, now: the results are dropped and the state is as it was.
         state, d = queue.state, queue.input_dim
-        for b in replay_buckets(log_cap):
+        for b in replay_buckets(log_capacity):
             rebuild_fn(state, 0, np.zeros((b, d), queue._dtype),
                        np.zeros((b,), queue._dtype))
         jax.block_until_ready(evict_fn(state, 0))

@@ -35,12 +35,16 @@ measures.
 Capacity management: ``suggest_size()`` proposes pow2 grow/shrink targets
 from occupancy and recent admission rejects; the facade applies them by
 migrating live rows (compaction) through the bank primitives.
+
+A server without a policy asks the same questions of an
+:class:`IdentityRouter` (tenant ``t`` is slot ``t``), so the facade runs
+one tenant lifecycle either way.
 """
 from __future__ import annotations
 
 from typing import Callable, NamedTuple, Optional, Union
 
-__all__ = ["AdmitDecision", "SlotPolicy", "SCORERS"]
+__all__ = ["AdmitDecision", "IdentityRouter", "SlotPolicy", "SCORERS"]
 
 
 class AdmitDecision(NamedTuple):
@@ -149,6 +153,16 @@ class SlotPolicy:
         """The slot serving ``tenant``, or None when not resident."""
         return self._resident.get(tenant)
 
+    def route(self, tenant: int) -> Optional[int]:
+        """Touch ``tenant`` for one request and return its slot (None when
+        not resident): the server's per-request lookup."""
+        self.touch(tenant)
+        return self._resident.get(tenant)
+
+    # No tenant is barred from the bank: a non-resident one is admitted on
+    # its next write, floor permitting.
+    evicted: frozenset = frozenset()
+
     @property
     def resident(self) -> dict[int, int]:
         """Snapshot of the tenant -> slot map."""
@@ -211,6 +225,15 @@ class SlotPolicy:
         if tenant not in self._resident:
             raise KeyError(f"tenant {tenant} is not resident")
         self._resident[tenant] = new_slot
+
+    def reset(self, slots: int) -> None:
+        """Forget every tenant (clock, touch history, residency) and manage
+        ``slots`` free slots."""
+        self.clock = 0
+        self.last_touch.clear()
+        self.touches.clear()
+        self._resident.clear()
+        self.set_slots(slots)
 
     # -- durability ---------------------------------------------------------
 
@@ -292,3 +315,57 @@ class SlotPolicy:
         self._free = sorted((s for s in range(slots) if s not in used),
                             reverse=True)
         self.rejects_since_resize = 0
+
+
+class IdentityRouter:
+    """The router of a server without a slot policy: tenant ``t`` is slot
+    ``t``, and ``slots`` bounds the ids.
+
+    ``release`` (``Server.evict``) puts a tenant *out*: its ``lookup``
+    misses and ``admit`` rejects it, so its writes are logged but not
+    trained, until a forced admission (``Server.readmit``) gives it its
+    own slot back. ``touch`` is a no-op: there is no clock to advance."""
+
+    def __init__(self, slots: int):
+        self.slots = slots
+        self.out: set[int] = set()
+
+    def lookup(self, tenant: int) -> Optional[int]:
+        return None if tenant in self.out else tenant
+
+    route = lookup  # no clock to touch
+
+    def touch(self, tenant: int) -> None:
+        pass
+
+    def admit(self, tenant: int, force: bool = False) -> AdmitDecision:
+        if tenant not in self.out:
+            return AdmitDecision("hit", slot=tenant)
+        if not force:
+            return AdmitDecision("reject")
+        self.out.discard(tenant)
+        return AdmitDecision("admit", slot=tenant)
+
+    def release(self, tenant: int) -> Optional[int]:
+        if tenant in self.out:
+            return None
+        self.out.add(tenant)
+        return tenant
+
+    @property
+    def evicted(self) -> frozenset:
+        return frozenset(self.out)
+
+    @property
+    def resident(self) -> dict[int, int]:
+        return {t: t for t in range(self.slots) if t not in self.out}
+
+    def reset(self, slots: int) -> None:
+        self.slots = slots
+        self.out.clear()
+
+    def state_dict(self) -> dict:
+        return {"evicted": sorted(self.out)}
+
+    def load_state(self, d: dict) -> None:
+        self.out = {int(t) for t in d["evicted"]}

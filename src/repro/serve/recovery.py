@@ -28,8 +28,8 @@ loop; obs/faults.py manufactures the failures that drive it in tests:
   ignored on read.
 * :func:`save_checkpoint` / :func:`restore_checkpoint` — crash-consistent
   serialization of a full ``serve.api.Server`` (bank state, queue
-  counters and pending buffers, replica version, slot policy, replay
-  logs, feature-map params) as atomically-renamed ``gen_N.ckpt`` files
+  counters and pending buffers, replica version, router state, replay
+  log, feature-map params) as atomically-renamed ``gen_N.ckpt`` files
   with generation numbers. Restore validates the config and the feature
   map bitwise, installs every leaf, and replays the WAL suffix recorded
   after the checkpoint through the ordinary submit path — so
@@ -67,7 +67,7 @@ __all__ = [
     "restore_checkpoint",
 ]
 
-CKPT_FORMAT = "repro.server.ckpt/v1"
+CKPT_FORMAT = "repro.server.ckpt/v2"
 
 # The escalation ladder, cheapest repair first. ``resymmetrize`` is only
 # offered to true RLS banks (a (B, D, D) P next to a theta row); every
@@ -459,21 +459,14 @@ class RecoveryPolicy:
         return True, ""
 
     def _rebuild(self, ep: _Episode) -> None:
+        # Pending arrivals are already in the tenant's log; drop the slot's
+        # backlog and replay the whole history into the slot — the row an
+        # operator's evict + readmit would write.
         server = self._server
         inner = server.snapshot_server
-        if server.policy is None:
-            # Slot-keyed log: evict + readmit IS the rebuild, bitwise the
-            # operator path a control server would take.
-            inner.evict(ep.tenant)
-            replayed = inner.readmit(ep.tenant)
-        else:
-            # Pending arrivals are already in the id-keyed log; drop the
-            # slot's backlog and replay the whole history into the slot.
-            inner.queue.drop_pending(ep.slot)
-            inner._arrival_times[ep.slot].clear()
-            inner.write_slot(ep.slot, server.log.arrays(ep.tenant))
-            replayed = server.log.size(ep.tenant)
-        server._expected[ep.slot] = replayed
+        inner.drop_backlog(ep.slot)
+        inner.write_slot(ep.slot, server.log.arrays(ep.tenant))
+        server._expected[ep.slot] = server.log.size(ep.tenant)
 
     def _verify(self, ep: _Episode) -> bool:
         """Check the repaired slot against the monitor's own thresholds."""
@@ -572,6 +565,8 @@ def _log_payload(log) -> Optional[dict]:
 
 
 def _load_log(log, payload: Optional[dict]) -> None:
+    if log is None:
+        return
     log.clear()
     if payload is None:
         return
@@ -589,9 +584,10 @@ def save_checkpoint(server, directory, *, keep: int = 3) -> str:
 
     The payload covers everything a fresh identically-configured server
     needs to resume bitwise: bank-state leaves, queue counters and
-    pending buffers, replica version/tick, the slot policy's decision
-    state, replay logs (with their ring-overflow counters), the evicted
-    set, the facade's expected-ticks ledger, and the WAL high-water mark.
+    pending buffers, replica version/tick, the router's state (the slot
+    policy's decisions, or the tenants put out without one), the replay
+    log (with its ring-overflow counters), the facade's expected-ticks
+    ledger, and the WAL high-water mark.
     The feature map's leaves ride along for bitwise validation at restore
     (the map itself is rebuilt by the caller's ``make_server``).
 
@@ -638,18 +634,8 @@ def save_checkpoint(server, directory, *, keep: int = 3) -> str:
                 "version": inner._snapshot.version,
                 "tick": inner._snapshot.tick,
             },
-            "policy": (
-                server.policy.state_dict()
-                if server.policy is not None
-                else None
-            ),
+            "router": server._router.state_dict(),
             "log": _log_payload(server.log),
-            "inner_log": (
-                _log_payload(inner.log)
-                if server.policy is not None
-                else None
-            ),
-            "evicted": sorted(inner._evicted),
             "expected": dict(server._expected),
             "wal_seq": server.wal.seq if server.wal is not None else -1,
         }
@@ -708,13 +694,8 @@ def _install(payload: dict, server) -> None:
     inner = server.snapshot_server
     queue = inner.queue
     if server.slots != payload["config"]["slots"]:
-        # Bank geometry is restored by resize (policy mode); without a
-        # policy the caller must build the server at the saved size.
-        if server.policy is None:
-            raise ValueError(
-                f"checkpoint has {payload['config']['slots']} slots, "
-                f"server has {server.slots}; rebuild at the saved size"
-            )
+        # Bank geometry is restored by resize, which needs a policy;
+        # without one the caller must build the server at the saved size.
         server.resize(payload["config"]["slots"])
     _, treedef = jax.tree_util.tree_flatten(queue.state)
     state = jax.tree_util.tree_unflatten(
@@ -736,14 +717,8 @@ def _install(payload: dict, server) -> None:
         version=int(payload["snapshot"]["version"]),
         tick=int(payload["snapshot"]["tick"]),
     )
-    inner._evicted = set(payload["evicted"])
-    if server.policy is not None:
-        server.policy.load_state(payload["policy"])
-        _load_log(server.log, payload["log"])
-        if inner.log is not None:
-            _load_log(inner.log, payload["inner_log"])
-    elif inner.log is not None:
-        _load_log(inner.log, payload["log"])
+    server._router.load_state(payload["router"])
+    _load_log(server.log, payload["log"])
     server._expected = {
         int(k): int(v) for k, v in payload["expected"].items()
     }

@@ -23,27 +23,26 @@ Everything stays host-side and synchronous like the queue itself (submit /
 flush / predict compose with any outer event loop; watermarks are checked
 on ``submit`` and via ``maybe_flush`` rather than from a thread).
 
-Tenant lifecycle rides on the same machinery: when a ``log_capacity`` is
-set, every arrival is also appended to a per-tenant :class:`ReplayLog`
-ring buffer, so ``evict(tenant)`` can release the slot as one O(1) row
-write (``core.bank.evict_tenant``) and ``readmit(tenant)`` reconstructs
-the state by replaying the log through the parallel-in-time engine
-(``core.bank.rebuild_tenant`` over core/scan.py) instead of keeping a cold
-copy of the ``(D,)``/``(D, D)`` state around. While evicted, a tenant's
-arrivals are *logged but not trained* — readmission folds them in.
+This tier is keyed by bank slot and knows no tenants. The tenant
+lifecycle lives in the serving facade (serve/api.py), which keeps one
+tenant-keyed :class:`ReplayLog` and moves rows through two slot hooks:
+``release_slot`` parks a fresh row (O(1), ``core.bank.evict_tenant``'s
+row) and ``write_slot`` writes a row rebuilt from a log by the
+parallel-in-time engine (``core.bank.rebuild_tenant`` over core/scan.py),
+so no cold copy of the ``(D,)``/``(D, D)`` state is kept around.
 """
 from __future__ import annotations
 
 import time
 from collections import deque
 from functools import partial
-from typing import Any, Callable, NamedTuple, Optional, Union
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bank import bank_predict_block, evict_tenant, rebuild_tenant
+from repro.core.bank import bank_predict_block, evict_tenant
 from repro.features.base import FeatureLike
 from repro.obs import telemetry as _telemetry
 from repro.obs import trace as _trace
@@ -54,8 +53,6 @@ __all__ = [
     "StateSnapshot",
     "SnapshotServer",
     "predict_row",
-    "klms_snapshot_server",
-    "krls_snapshot_server",
 ]
 
 
@@ -70,15 +67,12 @@ class ReplayLog:
     queue's pending deques); ``arrays`` materializes one ``(n, d)``/``(n,)``
     pair for the replay engine.
 
-    Keys are arbitrary ints materialized on first append — slot indices on
-    the snapshot tier, unbounded tenant *ids* on the policy tier
-    (serve/api.py), which is why storage is a dict rather than a
-    slot-indexed list. ``num_tenants`` is accepted for signature
-    compatibility but no longer pre-sizes anything.
+    Keys are tenant ids, materialized on first append: ids are unbounded
+    under a slot policy (serve/api.py), so storage is a dict rather than a
+    slot-indexed list.
     """
 
-    def __init__(self, num_tenants: int = 0, capacity: int = 256,
-                 dtype=np.float32):
+    def __init__(self, capacity: int = 256, dtype=np.float32):
         if capacity < 1:
             raise ValueError("log capacity must be >= 1")
         self.capacity = capacity
@@ -124,16 +118,6 @@ class ReplayLog:
         xs = np.stack([x for x, _ in buf])
         ys = np.asarray([y for _, y in buf], self._dtype)
         return xs, ys
-
-    def move(self, src: int, dst: int) -> None:
-        """Re-key one tenant's history (bank-compaction hook): ``dst``
-        takes over ``src``'s buffer and overflow counter, including when
-        ``src`` has none (``dst`` is then cleared)."""
-        self.clear(dst)
-        buf = self._buf.pop(src, None)
-        if buf is not None:
-            self._buf[dst] = buf
-            self._appended[dst] = self._appended.pop(src)
 
     def clear(self, tenant: Optional[int] = None) -> None:
         """Forget one tenant's history — including the overflow counter,
@@ -206,15 +190,12 @@ class SnapshotServer:
       size_watermark: observations — flush when any tenant's backlog
         reaches this depth.
       clock: injectable monotonic clock (tests pass a fake).
-      log_capacity: entries per tenant in the :class:`ReplayLog` ring
-        buffer. None (default) disables logging — ``evict`` still works
-        (the slot parks a fresh row) but ``readmit`` can only restart the
-        tenant cold.
-      evict_fn: ``(state, tenant) -> state`` releasing one slot; defaults
-        to ``core.bank.evict_tenant`` with its family-inferred fresh row.
-      rebuild_fn: ``(state, tenant, xs, ys) -> state`` replaying a log
-        into one slot; the factories wire ``core.bank.rebuild_tenant``
-        closures carrying the family hyperparameters and replay mode.
+      evict_fn: ``(state, slot) -> state`` parking a fresh row in one
+        slot; defaults to ``core.bank.evict_tenant`` with its
+        family-inferred fresh row.
+      rebuild_fn: ``(state, slot, xs, ys) -> state`` replaying a log into
+        one slot; ``make_server`` wires the family's rebuild with its
+        hyperparameters and replay mode.
     """
 
     def __init__(
@@ -228,7 +209,6 @@ class SnapshotServer:
         age_watermark: Optional[float] = None,
         size_watermark: Optional[int] = None,
         clock: Callable[[], float] = time.monotonic,
-        log_capacity: Optional[int] = None,
         evict_fn: Optional[Callable] = None,
         rebuild_fn: Optional[Callable] = None,
     ):
@@ -244,14 +224,8 @@ class SnapshotServer:
         self._clock = clock
         self._arrival_times = [deque() for _ in range(queue.num_tenants)]
         self._snapshot = StateSnapshot(state=queue.state, version=0, tick=0)
-        self.log = (
-            ReplayLog(queue.num_tenants, log_capacity, queue._dtype)
-            if log_capacity is not None
-            else None
-        )
         self._evict_fn = evict_fn if evict_fn is not None else evict_tenant
         self._rebuild_fn = rebuild_fn
-        self._evicted: set[int] = set()
 
     # -- read path ---------------------------------------------------------
 
@@ -297,25 +271,16 @@ class SnapshotServer:
 
     # -- write path --------------------------------------------------------
 
-    def submit(self, tenant: int, x, y) -> None:
-        """Enqueue one observation; flush if a watermark trips.
-
-        Every arrival is also appended to the replay log (when one is
-        configured). An *evicted* tenant's arrivals stop here: they are
-        logged but never queued, so the released slot stays untrained
-        until :meth:`readmit` folds the whole log back in.
-        """
-        if self.log is not None:
-            self.log.append(tenant, x, y)
-        if tenant in self._evicted:
-            return
+    def submit(self, slot: int, x, y) -> None:
+        """Enqueue one observation for ``slot``; flush if a watermark
+        trips."""
         # Tag the arrival with its backlog position, not just a count:
         # observations submitted straight to the queue (legal; they opt out
         # of the age watermark) occupy positions too, and a flush must
         # consume exactly the timestamps of the positions it served.
-        pos = len(self.queue._pending[tenant])
-        self._arrival_times[tenant].append((pos, self._clock()))
-        self.queue.submit(tenant, x, y)
+        pos = len(self.queue._pending[slot])
+        self._arrival_times[slot].append((pos, self._clock()))
+        self.queue.submit(slot, x, y)
         self.maybe_flush()
 
     def _consume_arrival_times(self, tenant: int, served: int) -> None:
@@ -389,73 +354,19 @@ class SnapshotServer:
                 merged.setdefault(tenant, []).extend(served)
         return merged
 
-    # -- tenant lifecycle --------------------------------------------------
+    # -- slot lifecycle ----------------------------------------------------
 
-    @property
-    def evicted(self) -> frozenset[int]:
-        """Tenants whose slots are currently released."""
-        return frozenset(self._evicted)
-
-    def evict(self, tenant: int) -> int:
-        """Release one bank slot: drop the tenant's pending observations,
-        park a fresh row in the slot (O(1) — ``core.bank.evict_tenant``),
-        and publish so readers stop seeing the old weights immediately.
-
-        The replay log is *kept*: it is the only record :meth:`readmit`
-        rebuilds from. Returns the number of pending observations dropped
-        (they were logged on submit, so readmission still replays them).
-        """
-        dropped = self.queue.drop_pending(tenant)
-        self._arrival_times[tenant].clear()
-        self._evicted.add(tenant)
-        self.write_slot(tenant)
-        return dropped
-
-    def readmit(self, tenant: int, mode: Optional[str] = None) -> int:
-        """Re-admit an evicted tenant by replaying its log into the slot.
-
-        The rebuild runs through ``rebuild_fn`` (the factories wire
-        ``core.bank.rebuild_tenant`` -> core/scan.py, so the slot is
-        reconstructed in O(log T) scan depth rather than T sequential
-        ticks), then a fresh replica is published. With no log or an empty
-        one the tenant simply restarts cold on the parked fresh row.
-        Returns the number of ticks replayed. If the ring overflowed
-        (``log.complete(tenant)`` is False) the rebuilt state is the
-        windowed one — fresh init + the last ``capacity`` ticks.
-        """
-        if tenant not in self._evicted:
-            raise ValueError(f"tenant {tenant} is not evicted")
-        logged = self.log is not None and self.log.size(tenant)
-        if logged and self._rebuild_fn is None:
-            raise ValueError(
-                "readmit with a non-empty log needs a rebuild_fn "
-                "(use the klms/krls factories or pass one)"
-            )
-        self._evicted.discard(tenant)
-        if not logged:
-            self.publish()
-            return 0
-        log = self.log.arrays(tenant)
-        with _trace.span(
-            "snapshot.rebuild",
-            tenant=tenant,
-            ticks=len(log[1]),
-            complete=self.log.complete(tenant),
-        ):
-            self.write_slot(tenant, log)
-        return len(log[1])
+    def drop_backlog(self, slot: int) -> int:
+        """Drop one slot's pending observations together with their
+        arrival times; returns the dropped count."""
+        self._arrival_times[slot].clear()
+        return self.queue.drop_pending(slot)
 
     def release_slot(self, slot: int) -> int:
-        """Release one bank slot *without* entering the evicted set (the
-        policy tier's eviction hook): drop its pending observations, clear
-        its arrival times, park a fresh row, publish. Unlike
-        :meth:`evict`, subsequent submits to this slot train normally —
-        the policy immediately reassigns the slot to another tenant, and
-        per-tenant history lives in the policy tier's id-keyed log, not
-        the slot-keyed one. Returns the dropped pending count."""
-        dropped = self.queue.drop_pending(slot)
-        self._arrival_times[slot].clear()
-        self._evicted.discard(slot)
+        """Release one bank slot: drop its backlog, park a fresh row and
+        publish, so readers stop seeing the old weights at once. Returns
+        the dropped pending count."""
+        dropped = self.drop_backlog(slot)
         self.write_slot(slot)
         return dropped
 
@@ -470,42 +381,19 @@ class SnapshotServer:
             self.queue.state = self._rebuild_fn(self.queue.state, slot, *log)
         self.publish()
 
-    def reset_tenant(self, tenant: int) -> int:
-        """Reset ONE tenant to a fresh slot: drop its pending
-        observations, clear its arrival times AND its replay-log history
-        — including the ring-overflow counter, so the slot reads
-        ``log.complete()`` again instead of inheriting the previous
-        occupant's stale truncation flag — park a fresh row, and leave
-        the evicted set. Returns the dropped pending count."""
-        dropped = self.queue.drop_pending(tenant)
-        self._arrival_times[tenant].clear()
-        if self.log is not None:
-            self.log.clear(tenant)
-        self._evicted.discard(tenant)
-        self.write_slot(tenant)
-        return dropped
-
     def move_slot(self, src: int, dst: int) -> None:
         """Transfer slot-local bookkeeping from ``src`` to ``dst`` (bank
         compaction; the caller moves the state row itself): pending
-        backlog, arrival counters and timestamps, evicted membership, and
-        slot-keyed log history. ``src`` is left empty."""
+        backlog, arrival counters and timestamps. ``src`` is left
+        empty."""
         self.queue.move_slot(src, dst)
         self._arrival_times[dst] = self._arrival_times[src]
         self._arrival_times[src] = deque()
-        if src in self._evicted:
-            self._evicted.discard(src)
-            self._evicted.add(dst)
-        else:
-            self._evicted.discard(dst)
-        if self.log is not None:
-            self.log.move(src, dst)
 
     def adopt_resized(self, state) -> None:
         """Adopt a grown/shrunk bank state (the policy tier's resize):
-        resize the queue's per-slot buffers and the arrival-time ledger,
-        drop lifecycle bookkeeping for truncated slots (which must be
-        empty — compact first), and publish."""
+        resize the queue's per-slot buffers and the arrival-time ledger
+        (truncated slots must be empty — compact first), and publish."""
         old = self.queue.num_tenants
         self.queue.adopt(state)
         new = self.queue.num_tenants
@@ -515,20 +403,13 @@ class SnapshotServer:
             )
         else:
             self._arrival_times = self._arrival_times[:new]
-            self._evicted = {s for s in self._evicted if s < new}
-            if self.log is not None:
-                for t in self.log.tenants():
-                    if t >= new:
-                        self.log.clear(t)
         self.publish()
 
     def reset(self, state) -> None:
         """Restart both buffers on a fresh bank state (tenant-eviction /
         benchmark hook): the live queue state AND the published replica
-        drop to version 0, and per-tenant lifecycle bookkeeping (arrival
-        counters, replay logs with their truncation flags, the evicted
-        set) is wiped with them. Pending observations must be drained
-        first."""
+        drop to version 0, and the per-slot arrival counters are wiped
+        with them. Pending observations must be drained first."""
         if self.queue.pending_total:
             raise RuntimeError("reset with pending observations; drain first")
         self.queue.state = state
@@ -536,9 +417,6 @@ class SnapshotServer:
         self.queue.arrivals = [0] * self.queue.num_tenants
         self._arrival_times = [deque() for _ in range(self.queue.num_tenants)]
         self._snapshot = StateSnapshot(state=state, version=0, tick=0)
-        if self.log is not None:
-            self.log.clear()
-        self._evicted.clear()
 
     def publish(self) -> StateSnapshot:
         """Swap the read replica to the live state (atomic: one reference
@@ -555,80 +433,3 @@ class SnapshotServer:
         )
         return self._snapshot
 
-
-def klms_snapshot_server(
-    rff: FeatureLike,
-    num_tenants: int,
-    mu: Union[float, jax.Array] = 0.5,
-    chunk: int = 16,
-    publish_every: int = 1,
-    mode: str = "auto",
-    precision: Optional[str] = None,
-    adaptive: bool = False,
-    rebuild_mode: str = "scan",
-    **kw,
-) -> SnapshotServer:
-    """Deprecated: use ``repro.serve.make_server(learner="klms", ...)``.
-
-    Thin shim preserving the historical contract (returns the bare
-    :class:`SnapshotServer`; per-tenant ``(B,)`` ``mu`` honored)."""
-    from repro.serve import api
-
-    api._deprecated(
-        "klms_snapshot_server", 'make_server(learner="klms", ...)'
-    )
-    queue = api.make_queue(
-        "klms", rff, num_tenants, chunk=chunk, mode=mode,
-        adaptive=adaptive, mu=mu,
-    )
-    kw.setdefault(
-        "rebuild_fn",
-        lambda state, tenant, xs, ys: rebuild_tenant(
-            state, tenant, rff, xs, ys, mu=mu, mode=rebuild_mode
-        ),
-    )
-    return SnapshotServer(
-        queue, rff, publish_every, mode=mode, precision=precision, **kw
-    )
-
-
-def krls_snapshot_server(
-    rff: FeatureLike,
-    num_tenants: int,
-    lam: Union[float, jax.Array] = 1e-4,
-    beta: Union[float, jax.Array] = 0.9995,
-    chunk: int = 16,
-    publish_every: int = 1,
-    mode: str = "auto",
-    precision: Optional[str] = None,
-    adaptive: bool = False,
-    rebuild_mode: str = "scan",
-    **kw,
-) -> SnapshotServer:
-    """Deprecated: use ``repro.serve.make_server(learner="krls", ...)``.
-
-    Thin shim preserving the historical contract (returns the bare
-    :class:`SnapshotServer`; per-tenant ``(B,)`` ``lam``/``beta``
-    honored)."""
-    from repro.serve import api
-
-    api._deprecated(
-        "krls_snapshot_server", 'make_server(learner="krls", ...)'
-    )
-    queue = api.make_queue(
-        "krls", rff, num_tenants, chunk=chunk, mode=mode,
-        adaptive=adaptive, lam=lam, beta=beta,
-    )
-    kw.setdefault(
-        "evict_fn",
-        lambda state, tenant: evict_tenant(state, tenant, lam=lam),
-    )
-    kw.setdefault(
-        "rebuild_fn",
-        lambda state, tenant, xs, ys: rebuild_tenant(
-            state, tenant, rff, xs, ys, lam=lam, beta=beta, mode=rebuild_mode
-        ),
-    )
-    return SnapshotServer(
-        queue, rff, publish_every, mode=mode, precision=precision, **kw
-    )

@@ -46,11 +46,7 @@ from repro.core.learner import (
 )
 from repro.core.rff import sample_rff
 from repro.serve import make_server
-from repro.serve.snapshot import (
-    ReplayLog,
-    klms_snapshot_server,
-    krls_snapshot_server,
-)
+from repro.serve.snapshot import ReplayLog
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -175,7 +171,7 @@ def test_tenant_row_roundtrip(key):
 
 
 def test_replay_log_ring_semantics():
-    log = ReplayLog(2, capacity=4)
+    log = ReplayLog(capacity=4)
     for i in range(6):
         log.append(0, np.full(3, i, np.float32), float(i))
     assert log.size(0) == 4
@@ -207,43 +203,96 @@ def _obs(seed, n, tenants=3):
     ]
 
 
+@pytest.mark.parametrize("policy", [None, "lru"])
 @pytest.mark.parametrize("family", ["klms", "krls"])
-def test_server_evict_readmit_matches_never_evicted(family):
-    make = {
-        "klms": lambda: klms_snapshot_server(
-            _RFF, 3, mu=0.3, chunk=8, log_capacity=512
-        ),
-        "krls": lambda: krls_snapshot_server(
-            _RFF, 3, lam=0.1, beta=0.99, chunk=8, log_capacity=512
-        ),
-    }[family]
+def test_server_evict_readmit_matches_never_evicted(family, policy):
+    """Without a policy an evicted tenant's arrivals only log until
+    ``readmit``; under LRU with a slot per tenant its next arrival
+    readmits it. Either way the rebuilt row matches the never-evicted one
+    and every other row is untouched."""
+
+    def make():
+        return make_server(
+            family, feature_map=_RFF, bank=3, chunk=8, log_capacity=512,
+            policy=policy, **_SERVER_HP[family],
+        )
+
     srv, ctl = make(), make()
     obs = _obs(7, 240)
     _drive(ctl, obs)
 
     _drive(srv, obs[:100])
+    slot = srv.resident[1]
     srv.evict(1)
-    assert 1 in srv.evicted
-    # While evicted: reads serve the parked fresh row, arrivals only log.
+    assert 1 not in srv.resident
+    # While evicted: reads serve the parked fresh row.
     if family == "klms":
-        assert float(jnp.abs(srv.snapshot.state.theta[1]).max()) == 0.0
-    _drive(srv, obs[100:])
-    assert srv.queue.backlog()[1] == 0  # nothing queued while evicted
-
+        assert float(jnp.abs(srv.snapshot.state.theta[slot]).max()) == 0.0
     n1 = sum(1 for t, _, _ in obs if t == 1)
-    assert srv.readmit(1) == n1
-    assert 1 not in srv.evicted
-    assert _rel(srv.snapshot.state.theta[1], ctl.snapshot.state.theta[1]) < 5e-5
+    if policy is None:
+        assert 1 in srv.evicted
+        _drive(srv, obs[100:])
+        assert srv.queue.backlog()[1] == 0  # nothing queued while evicted
+        assert srv.readmit(1) == n1
+        assert 1 not in srv.evicted
+    else:
+        assert srv.evicted == frozenset()
+        _drive(srv, obs[100:])
+        assert srv.metrics.count("readmissions") == 1
+        assert srv.readmit(1) == 0  # already back
+    assert srv.resident == ctl.resident
+    assert _rel(
+        srv.snapshot.state.theta[slot], ctl.snapshot.state.theta[slot]
+    ) < 5e-5
     # Untouched tenants are bit-identical to the control server.
-    for b in (0, 2):
+    for b in set(range(3)) - {slot}:
         _assert_trees_equal(
             tenant_row(srv.snapshot.state, b), tenant_row(ctl.snapshot.state, b)
         )
 
 
+@pytest.mark.parametrize("family", ["klms", "krls"])
+def test_identity_and_lru_servers_share_one_lifecycle(family):
+    """A server without a policy and an LRU server that never has to evict
+    run one tenant lifecycle: the same traffic with ``evict``, ``readmit``
+    and ``reset_tenant`` of one tenant ends in bitwise-equal live state,
+    replica and flush priors."""
+    servers = [
+        make_server(
+            family, feature_map=_RFF, bank=3, chunk=8, log_capacity=64,
+            policy=policy, rebuild_mode="sequential", **_SERVER_HP[family],
+        )
+        for policy in (None, "lru")
+    ]
+    # First arrivals in tenant order, so LRU places tenant t in slot t.
+    head = [(t, np.full(3, 0.1 * t, np.float32), 1.0) for t in range(3)]
+    obs = head + _obs(17, 90)
+    others = [a for a in _obs(19, 60) if a[0] != 1]
+
+    def run(srv):
+        priors = []
+        for part, op in ((obs[:60], srv.evict), (others, srv.readmit),
+                         (obs[60:], srv.reset_tenant), (others, None)):
+            for t, x, y in part:
+                srv.submit(t, x, y)
+            priors.append(srv.drain())
+            if op is not None:
+                op(1)
+        return priors
+
+    ident, lru = servers
+    p_ident, p_lru = run(ident), run(lru)
+    assert ident.policy is None and lru.policy is not None
+    assert ident.resident == lru.resident == {0: 0, 1: 1, 2: 2}
+    assert ident.metrics.count("readmissions") == 1
+    assert p_ident == p_lru
+    _assert_trees_equal(ident.queue.state, lru.queue.state)
+    _assert_trees_equal(ident.snapshot.state, lru.snapshot.state)
+
+
 def test_server_sequential_readmit_is_bitwise():
-    srv = klms_snapshot_server(
-        _RFF, 3, mu=0.3, chunk=8, log_capacity=512,
+    srv = make_server(
+        "klms", feature_map=_RFF, bank=3, mu=0.3, chunk=8, log_capacity=512,
         rebuild_mode="sequential",
     )
     obs = _obs(11, 200)
@@ -257,8 +306,9 @@ def test_server_sequential_readmit_is_bitwise():
 
 
 def test_server_evict_drops_pending_and_publishes():
-    srv = klms_snapshot_server(
-        _RFF, 2, mu=0.3, chunk=16, log_capacity=64, publish_every=1000
+    srv = make_server(
+        "klms", feature_map=_RFF, bank=2,
+        mu=0.3, chunk=16, log_capacity=64, publish_every=1000
     )
     rng = np.random.default_rng(0)
     for _ in range(5):
@@ -274,7 +324,8 @@ def test_server_evict_drops_pending_and_publishes():
 def test_server_readmit_overflowed_log_is_windowed():
     """Ring overflow -> readmission rebuilds fresh-init + last `capacity`
     ticks, and the log flags the truncation."""
-    srv = klms_snapshot_server(_RFF, 2, mu=0.3, chunk=8, log_capacity=16)
+    srv = make_server("klms", feature_map=_RFF, bank=2, mu=0.3, chunk=8,
+                      log_capacity=16)
     obs = [(0, x, y) for _, x, y in _obs(13, 40)]
     _drive(srv, obs)
     srv.evict(0)
@@ -287,7 +338,8 @@ def test_server_readmit_overflowed_log_is_windowed():
 
 
 def test_server_reset_clears_lifecycle_state():
-    srv = klms_snapshot_server(_RFF, 2, mu=0.3, log_capacity=8)
+    srv = make_server("klms", feature_map=_RFF, bank=2, mu=0.3,
+                      log_capacity=8)
     srv.submit(0, np.zeros(3, np.float32), 1.0)
     srv.drain()
     srv.evict(0)
@@ -304,7 +356,8 @@ def test_reset_tenant_clears_stale_log_truncation_flags():
     fix, the next occupant of the slot inherited ``complete() == False``
     from the previous tenant and a later evict/rebuild silently replayed
     a truncated (empty) history as if it were the full stream."""
-    srv = klms_snapshot_server(_RFF, 3, mu=0.3, chunk=8, log_capacity=16)
+    srv = make_server("klms", feature_map=_RFF, bank=3, mu=0.3, chunk=8,
+                      log_capacity=16)
     obs = _obs(11, 120)
     _drive(srv, obs)
     assert srv.log.dropped(1) > 0 and not srv.log.complete(1)
@@ -323,7 +376,8 @@ def test_reset_tenant_clears_stale_log_truncation_flags():
     # The slot trains normally again, identical to a fresh server fed the
     # same post-reset stream.
     post = [(t, x, y) for (t, x, y) in _obs(13, 80) if t == 1]
-    ctl = klms_snapshot_server(_RFF, 3, mu=0.3, chunk=8, log_capacity=16)
+    ctl = make_server("klms", feature_map=_RFF, bank=3, mu=0.3, chunk=8,
+                      log_capacity=16)
     _drive(srv, post)
     _drive(ctl, post)
     assert bool(

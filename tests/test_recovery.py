@@ -148,6 +148,32 @@ def test_checkpoint_restore_roundtrip_bitwise(
     assert _leaves_equal(a.queue.state, b.queue.state)
 
 
+def test_checkpoint_restores_evicted_tenants_without_policy(tmp_path):
+    """A server without a policy checkpoints its identity router: an
+    evicted tenant stays out after restore (its writes only log), and
+    readmitting it rebuilds bitwise what the never-killed server does."""
+    args = dict(feature_map=_RFF, bank=3, chunk=4, mu=0.3, log_capacity=64)
+    a = make_server("klms", **args)
+    traffic = _traffic(11, 40)
+    for t, x, y in traffic[:20]:
+        a.submit(t, x, y)
+    a.evict(1)
+    a.checkpoint(tmp_path / "ckpt")
+    b = make_server("klms", **args)
+    restore_checkpoint(b, tmp_path / "ckpt")
+    assert b.evicted == a.evicted == frozenset({1})
+    assert b.log.size(1) == a.log.size(1) > 0
+    for srv in (a, b):
+        for t, x, y in traffic[20:]:
+            srv.submit(t, x, y)
+        assert srv.queue.backlog()[1] == 0
+        srv.readmit(1)
+        srv.drain()
+    assert a.evicted == b.evicted == frozenset()
+    assert _leaves_equal(a.queue.state, b.queue.state)
+    assert _leaves_equal(a.snapshot.state, b.snapshot.state)
+
+
 def test_checkpoint_preserves_ring_overflow_flag(tmp_path):
     args = dict(
         feature_map=_RFF, bank=2, chunk=4, policy="lru",

@@ -162,29 +162,6 @@ def test_server_chunked_matches_lockstep_reference(learner, kw):
         assert_trees_bitwise(ref_state, srv.queue.state)
 
 
-def test_old_snapshot_server_matches_facade_server():
-    from repro.serve.snapshot import klms_snapshot_server
-
-    traffic = ragged_traffic(n=40, seed=4)
-    old = klms_snapshot_server(RFF, 4, mu=0.3, chunk=4, log_capacity=8)
-    new = api.make_server(
-        "klms", feature_map=RFF, bank=4, chunk=4, mu=0.3, log_capacity=8
-    )
-    for t, x, y in traffic:
-        old.submit(t, x, y)
-        new.submit(t, x, y)
-    old.drain()
-    new.drain()
-    old.evict(2)
-    new.evict(2)
-    assert old.readmit(2) == new.readmit(2)
-    assert_trees_bitwise(old.queue.state, new.queue.state)
-    q = np.zeros(D_IN, np.float32)
-    np.testing.assert_array_equal(
-        np.asarray(old.predict(1, q)), np.asarray(new.predict(1, q))
-    )
-
-
 def test_reset_slots_matches_old_resets():
     from repro.serve.bank_loop import reset_krls_tenants, reset_tenants
 
@@ -223,8 +200,6 @@ OLD_NAMES = [
     "make_chunked_krls_bank_server",
     "klms_micro_batch_queue",
     "krls_micro_batch_queue",
-    "klms_snapshot_server",
-    "krls_snapshot_server",
 ]
 
 
@@ -268,8 +243,6 @@ def test_deprecation_warning_fires_exactly_once_per_name():
         "krls_micro_batch_queue": lambda: serve.krls_micro_batch_queue(
             RFF, 2
         ),
-        "klms_snapshot_server": lambda: serve.klms_snapshot_server(RFF, 2),
-        "krls_snapshot_server": lambda: serve.krls_snapshot_server(RFF, 2),
     }
     assert set(calls) == set(OLD_NAMES)
     for name, call in calls.items():

@@ -13,7 +13,7 @@ from repro.features.base import featurize
 from repro.obs import telemetry
 from repro.obs.faults import Fault, FaultInjector, FaultPlan
 from repro.serve import api as serve_api
-from repro.serve import klms_snapshot_server, krls_snapshot_server, make_server
+from repro.serve import make_server
 from repro.serve.snapshot import SnapshotServer
 
 RFF = sample_rff(jax.random.PRNGKey(0), 4, 48, sigma=3.0)
@@ -74,8 +74,9 @@ def test_interleaved_reads_consistent_with_publish_boundaries():
     """Deterministic interleaving: reads between flushes keep returning the
     frozen replica even as the live state trains past it."""
     publish_every = 6
-    srv = klms_snapshot_server(
-        RFF, 3, mu=0.5, chunk=4, publish_every=publish_every, mode="xla"
+    srv = make_server(
+        "klms", feature_map=RFF, bank=3,
+        mu=0.5, chunk=4, publish_every=publish_every, mode="xla"
     )
     schedule = []
     i = 0
@@ -96,8 +97,9 @@ def test_reads_are_stale_until_publish():
     """publish_every > backlog: flushes advance the live state while the
     replica (and therefore reads) stay at version 0 until enough ticks
     accumulate — the read path provably does NOT track the live state."""
-    srv = klms_snapshot_server(
-        RFF, 1, mu=0.5, chunk=4, publish_every=100, mode="xla"
+    srv = make_server(
+        "klms", feature_map=RFF, bank=1,
+        mu=0.5, chunk=4, publish_every=100, mode="xla"
     )
     p0 = float(srv.predict(0, XS[0]))
     for i in range(8):
@@ -107,14 +109,15 @@ def test_reads_are_stale_until_publish():
     assert srv.queue.ticks_served == 8
     assert srv.snapshot.version == 0 and srv.staleness == 8
     assert float(srv.predict(0, XS[0])) == p0  # frozen replica, frozen read
-    srv.publish()  # manual publish releases the new state to readers
+    srv.snapshot_server.publish()  # manual publish releases the new state
     assert srv.staleness == 0
     assert float(srv.predict(0, XS[0])) != p0
 
 
 def test_size_watermark_background_flush():
-    srv = klms_snapshot_server(
-        RFF, 2, mu=0.5, chunk=8, publish_every=1, mode="xla", size_watermark=3
+    srv = make_server(
+        "klms", feature_map=RFF, bank=2,
+        mu=0.5, chunk=8, publish_every=1, mode="xla", size_watermark=3
     )
     srv.submit(0, XS[0], YS[0])
     srv.submit(1, XS[1], YS[1])
@@ -128,9 +131,9 @@ def test_size_watermark_background_flush():
 
 def test_age_watermark_background_flush():
     clock = FakeClock()
-    srv = klms_snapshot_server(
-        RFF,
-        2,
+    srv = make_server(
+        "klms", feature_map=RFF,
+        bank=2,
         mu=0.5,
         chunk=8,
         publish_every=1,
@@ -154,8 +157,9 @@ def test_direct_queue_flush_still_counts_toward_publish():
     """Publish due-ness derives from replica staleness, so ticks applied by
     calling queue.flush() directly (the queue's own API) still trigger a
     publish at the server's next flush."""
-    srv = klms_snapshot_server(
-        RFF, 1, mu=0.5, chunk=4, publish_every=3, mode="xla"
+    srv = make_server(
+        "klms", feature_map=RFF, bank=1,
+        mu=0.5, chunk=4, publish_every=3, mode="xla"
     )
     for i in range(4):
         srv.queue.submit(0, XS[i], YS[i])
@@ -170,9 +174,9 @@ def test_age_watermark_survives_interleaved_direct_submits():
     """A timed observation keeps its deadline even when untimed direct
     queue submissions sit ahead of it in the backlog."""
     clock = FakeClock()
-    srv = klms_snapshot_server(
-        RFF,
-        1,
+    srv = make_server(
+        "klms", feature_map=RFF,
+        bank=1,
         mu=0.5,
         chunk=1,  # one observation per flush: the direct one goes first
         publish_every=1,
@@ -190,8 +194,9 @@ def test_age_watermark_survives_interleaved_direct_submits():
 
 
 def test_krls_snapshot_server_predicts_from_replica():
-    srv = krls_snapshot_server(
-        RFF, 2, lam=1e-2, chunk=8, publish_every=4, mode="xla"
+    srv = make_server(
+        "krls", feature_map=RFF, bank=2,
+        lam=1e-2, chunk=8, publish_every=4, mode="xla"
     )
     for i in range(6):
         srv.submit(0, XS[i], YS[i])
@@ -206,8 +211,9 @@ def test_krls_snapshot_server_predicts_from_replica():
 
 
 def test_predict_block_precision_knob():
-    srv = klms_snapshot_server(
-        RFF, 2, mu=0.5, chunk=8, publish_every=1, mode="xla", precision="bf16"
+    srv = make_server(
+        "klms", feature_map=RFF, bank=2,
+        mu=0.5, chunk=8, publish_every=1, mode="xla", precision="bf16"
     )
     for i in range(8):
         srv.submit(0, XS[i], YS[i])
@@ -221,7 +227,7 @@ def test_predict_block_precision_knob():
 
 
 def test_reset_requires_drained_queue():
-    srv = klms_snapshot_server(RFF, 1, chunk=4, mode="xla")
+    srv = make_server("klms", feature_map=RFF, bank=1, chunk=4, mode="xla")
     srv.submit(0, XS[0], YS[0])
     with pytest.raises(RuntimeError):
         srv.reset(srv.queue.state)
@@ -429,8 +435,9 @@ if _HAVE_HYPOTHESIS:
     @given(schedule=_ops, publish_every=st.integers(1, 9))
     @settings(max_examples=15, deadline=None)
     def test_any_interleaving_no_torn_reads(schedule, publish_every):
-        srv = klms_snapshot_server(
-            RFF, 3, mu=0.5, chunk=4, publish_every=publish_every, mode="xla"
+        srv = make_server(
+            "klms", feature_map=RFF, bank=3,
+            mu=0.5, chunk=4, publish_every=publish_every, mode="xla"
         )
         _drive(srv, schedule, publish_every)
         srv.drain()
